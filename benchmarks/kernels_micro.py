@@ -12,6 +12,7 @@ writes the serving half to ``BENCH_serve.json``).
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.lut import build_lut
 from repro.kernels import ref
 from repro.kernels.flash_decode import flash_decode_paged
@@ -170,6 +171,7 @@ def run() -> None:
 
 
 def main(argv=None) -> None:
+    enable_compile_cache()
     import argparse
     import os
     ap = argparse.ArgumentParser(description=__doc__)

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_smoke_config
 from repro.core.kv_codebook import KVCodebook
 from repro.core.lut import DENSE
@@ -212,6 +213,7 @@ def _merge_snapshot(path: str) -> None:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="assert the acceptance ceilings (ppl delta <= "

@@ -9,8 +9,11 @@ from __future__ import annotations
 import argparse
 import sys
 
+from repro.compile_cache import enable_compile_cache
+
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="",
                     help="prefix filter: table1|table2|table45|table789|"

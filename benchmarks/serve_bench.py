@@ -15,8 +15,7 @@ wired into CI so the serving A/B cannot bit-rot.
 ``--sharded`` adds a tensor-parallel row: the same workload through
 ``Engine(mesh=...)`` over all visible devices, token-checked against the
 single-device continuous run. On a CPU host pass ``--devices N`` to
-re-exec with N forced host devices (XLA host-platform override) — the row
-then measures dispatch overhead, not real TP speedup (host "devices" share
+run on N host devices (``jax_num_cpu_devices``) — the row then measures dispatch overhead, not real TP speedup (host "devices" share
 the same cores; see docs/serving.md §Sharded serving).
 
 The prefix-reuse row replays a shared-system-prompt workload (one warming
@@ -89,12 +88,12 @@ from __future__ import annotations
 
 import argparse
 import os
-import sys
 import time
 
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_smoke_config
 from repro.core.lut import DENSE
 from repro.models.model import Model
@@ -673,6 +672,7 @@ def bench(slots: int, n_requests: int, max_seq: int, smoke: bool,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="CPU smoke config + 1.3x assertion (CI)")
@@ -680,8 +680,8 @@ def main():
                     help="add a tensor-parallel engine row over all "
                          "visible devices (token-checked vs single-device)")
     ap.add_argument("--devices", type=int, default=0,
-                    help="re-exec with N forced host devices "
-                         "(XLA host-platform override, for --sharded on CPU)")
+                    help="run on N devices; on a CPU host, N host devices "
+                         "(for --sharded on CPU)")
     ap.add_argument("--spec", action="store_true",
                     help="add the speculative-decoding A/B rows (trains a "
                          "small-vocab smoke model first; with --smoke, "
@@ -716,21 +716,15 @@ def main():
     args = ap.parse_args()
     if args.trace and not args.chaos:
         ap.error("--trace requires --chaos (it exports the faulted run)")
-    if args.devices and jax.device_count() < args.devices:
-        # one-shot sentinel: the host-platform override only adds devices on
-        # the CPU backend, so on a GPU/TPU host the re-exec'd process would
-        # still be short and exec forever without it
-        if os.environ.get("_SERVE_BENCH_REEXEC"):
+    if args.devices:
+        # Set before any backend starts: the CPU backend then holds N host
+        # devices (an accelerator backend is unaffected). Everything runs
+        # in this one process, which keeps every chip it takes.
+        jax.config.update("jax_num_cpu_devices", args.devices)
+        if jax.device_count() < args.devices:
             raise SystemExit(
-                f"--devices {args.devices}: still only {jax.device_count()} "
-                "device(s) after the host-platform override (non-CPU "
-                "backend?); run on CPU or drop --devices")
-        flags = os.environ.get("XLA_FLAGS", "")
-        env = dict(os.environ)
-        env["_SERVE_BENCH_REEXEC"] = "1"
-        env["XLA_FLAGS"] = (f"{flags} --xla_force_host_platform_device_count="
-                            f"{args.devices}").strip()
-        os.execve(sys.executable, [sys.executable] + sys.argv, env)
+                f"--devices {args.devices}: only {jax.device_count()} "
+                f"{jax.default_backend()} device(s) visible")
     bench(args.slots, args.requests, args.max_seq, args.smoke, args.sharded,
           args.devices, args.spec, args.chaos, args.longctx, args.kvq,
           args.obs, args.trace)

@@ -11,6 +11,7 @@ import argparse
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_smoke_config
 from repro.core import precompute_model
 from repro.core.lut import DENSE, QuantConfig
@@ -21,6 +22,7 @@ from repro.train import TrainConfig, Trainer
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=120)
     ap.add_argument("--v", type=int, default=4)

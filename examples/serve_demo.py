@@ -45,6 +45,7 @@ import numpy as np
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_smoke_config
 from repro.core import precompute_model
 from repro.core.lut import DENSE, QuantConfig
@@ -187,6 +188,7 @@ def chaos_demo(model, params, tracer=None) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--chaos", action="store_true",
                     help="serve a bursty SLO'd trace through 2 replicas "

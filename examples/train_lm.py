@@ -13,6 +13,7 @@ import argparse
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.lut import DENSE, QuantConfig
 from repro.data import SyntheticDataset
 from repro.models.config import ModelConfig
@@ -32,6 +33,7 @@ def model_config(full: bool) -> ModelConfig:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=25)
     ap.add_argument("--batch", type=int, default=8)

@@ -62,10 +62,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from .findings import Finding
 
-try:                                   # jax >= 0.4.16 moved core types
-    from jax.extend.core import ClosedJaxpr, Jaxpr        # type: ignore
-except Exception:                      # pragma: no cover - version shim
-    from jax.core import ClosedJaxpr, Jaxpr               # type: ignore
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 #: primitives that move data between host and device inside a trace
 TRANSFER_PRIMS = ("device_put",)

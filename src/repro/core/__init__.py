@@ -8,7 +8,7 @@ from .lut import (DENSE, QuantConfig, build_lut, lut_linear_apply,
                   strip_for_inference)
 from .lutboost import (LutBoostSchedule, apply_mask, capture_activations,
                        centroid_only_mask, convert, kmeans_init_from_capture,
-                       precompute_model, stage_mask)
+                       precompute_model, stage_mask, strip_model)
 from .similarity import (ALPHA_SIM, Metric, assign, assign_subspaces,
                          pairwise_distance, pairwise_distance_subspaces,
                          soft_assignment, ste_quantize,

@@ -21,6 +21,7 @@ Parameters of one LutLinear (a plain pytree dict):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -119,28 +120,59 @@ def quantize_lut_int8(lut: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return lut8.astype(jnp.int8), scale.astype(jnp.float32)
 
 
+def _inference_tables(w: jax.Array, z: jax.Array, lut_dtype: str):
+    """One projection's serving tables: ``(lut,)`` or ``(lut8, scale)``."""
+    lut = build_lut(w, z)
+    if lut_dtype == "int8":
+        return quantize_lut_int8(lut)
+    if lut_dtype == "bfloat16":
+        return (lut.astype(jnp.bfloat16),)
+    return (lut,)
+
+
 def precompute_layer(p: Params, qc: QuantConfig) -> Params:
     """Turn a trained LutLinear into its inference form (adds lut/scale).
 
     Handles leading batch dims on (w, z) — stacked scan layers (L, ...) and
-    per-expert weights (L, E, ...) — by vmapping the table construction.
+    per-expert weights (L, E, ...). The stacked layer axis is walked with
+    ``lax.map``, so only one layer's fp32 table exists at a time (a
+    full-width MLP table is ~140 MB per layer, ~5.7 GB for all 40).
     """
     if "z" not in p:
         return p
-    build = build_lut
-    quant = quantize_lut_int8
-    for _ in range(p["z"].ndim - 3):
-        build = jax.vmap(build)
-        quant = jax.vmap(quant)
-    lut = build(p["w"], p["z"])
+    tables = functools.partial(_inference_tables, lut_dtype=qc.lut_dtype)
+    lead = p["z"].ndim - 3
+    for _ in range(max(lead - 1, 0)):         # inner (expert) dims
+        tables = jax.vmap(tables)
+    if lead:
+        per_layer = tables
+        tables = lambda w, z: jax.lax.map(lambda wz: per_layer(*wz), (w, z))
     out = dict(p)
+    built = jax.jit(tables)(p["w"], p["z"])
+    out["lut"] = built[0]
     if qc.lut_dtype == "int8":
-        out["lut"], out["lut_scale"] = quant(lut)
-    elif qc.lut_dtype == "bfloat16":
-        out["lut"] = lut.astype(jnp.bfloat16)
-    else:
-        out["lut"] = lut
+        out["lut_scale"] = built[1]
     return out
+
+
+def missing_luts(params) -> list:
+    """Key paths of the LutLinear layers (those carrying centroids ``z``)
+    in ``params`` that have no precomputed ``lut``."""
+    found = []
+
+    def walk(tree, path):
+        if isinstance(tree, dict):
+            if "z" in tree:
+                if "lut" not in tree:
+                    found.append(path or "/")
+                return
+            for k, v in tree.items():
+                walk(v, f"{path}/{k}")
+        elif isinstance(tree, (list, tuple)):
+            for i, v in enumerate(tree):
+                walk(v, f"{path}/{i}")
+    walk(params, "")
+    return found
 
 
 def strip_for_inference(p: Params) -> Params:
@@ -149,10 +181,13 @@ def strip_for_inference(p: Params) -> Params:
 
 
 def lut_linear_apply(p: Params, x: jax.Array, qc: QuantConfig,
+                     tp: Optional[str] = None,
                      ) -> Tuple[jax.Array, jax.Array]:
     """Apply the projection. Returns (out, recon_loss_scalar).
 
     x: (..., K) -> (..., N). recon_loss is 0.0 except in lut_train mode.
+    tp: "col" | "row" tensor-parallel role, passed to the fused kernel
+    dispatch (see :func:`repro.kernels.ops.vq_amm`).
     """
     from . import lutboost  # circular-safe: only used for capture hook
     lutboost.record_activation(p, x)
@@ -191,12 +226,14 @@ def lut_linear_apply(p: Params, x: jax.Array, qc: QuantConfig,
     if qc.mode == "lut_infer":
         x2d = xs.reshape(-1, k // qc.v, qc.v)
         lut = p.get("lut")
-        if lut is None:                    # on-the-fly (testing convenience)
+        if lut is None:
+            # on-the-fly build for direct calls only: serving engines
+            # refuse params without tables (core.lut.missing_luts)
             lut = build_lut(p["w"], p["z"])
         if qc.fuse:
             # CCM pipelined into IMM: no (M, nc) index tensor in HBM.
             out = kops.vq_amm(x2d, p["z"], lut, p.get("lut_scale"),
-                              qc.metric, impl=qc.impl)
+                              qc.metric, impl=qc.impl, tp=tp)
         else:
             idx = kops.vq_assign(x2d, p["z"], qc.metric, impl=qc.impl)
             out = kops.lut_matmul(idx, lut, p.get("lut_scale"), impl=qc.impl)

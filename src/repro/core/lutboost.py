@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .codebook import kmeans_codebook
-from .lut import QuantConfig, precompute_layer
+from .lut import QuantConfig, precompute_layer, strip_for_inference
 
 # ---------------------------------------------------------------------------
 # Stage ①: calibration capture + k-means init
@@ -183,6 +183,12 @@ def apply_mask(grads, mask):
 # ---------------------------------------------------------------------------
 # Deployment: precompute every LUT
 # ---------------------------------------------------------------------------
+
+def strip_model(params):
+    """Drop every dense weight whose layer has a precomputed LUT
+    (:func:`~repro.core.lut.strip_for_inference` over the whole tree)."""
+    return _walk_lut_layers(params, strip_for_inference)
+
 
 def precompute_model(params, qc: QuantConfig):
     """Build inference LUTs for every LutLinear in the tree (paper step-2).

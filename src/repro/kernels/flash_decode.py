@@ -54,8 +54,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-from .tuning import select_blocks
+from .ops import require_local, tp_size
+from .tuning import flash_head_tile, select_blocks
 
 # Finite stand-in for -inf. exp(NEG_INF - NEG_INF) == 1 (not NaN), which
 # is exactly what makes the identity triple compose safely.
@@ -97,7 +99,8 @@ def reduce_splits(m: jax.Array, l: jax.Array, acc: jax.Array):
 def _split_masks(pos, win, ks, kj):
     """Shared causal/window/kv_start mask. kj broadcasts against pos."""
     mask = (kj < pos) & (kj >= ks)
-    return mask & jnp.where(win > 0, kj > pos - win, True)
+    # boolean or, not a select of booleans: Mosaic cannot lower the latter
+    return mask & ((win <= 0) | (kj > pos - win))
 
 
 def flash_decode_splits(qg: jax.Array, k_pages: jax.Array,
@@ -232,27 +235,42 @@ def _splits_pallas(qg, k_pages, v_pages, phys, pos, win, ks,
 # dequantizes (or LUT-accumulates) in VMEM / registers.
 # ---------------------------------------------------------------------------
 
-def _deq_tile(codes, z, s):
-    """In-kernel dequant of one code tile via one-hot matmul (MXU form).
+def _deq_tile(codes, rep, table, s):
+    """In-kernel dequant of one code tile as two 2-D MXU matmuls.
 
-    codes (ps, bh, nc) int32; z (nc, c, v) f32; s (bh,) f32 scales.
-    Returns fp K or V rows (bh, ps, nc*v) — never round-tripped to HBM.
+    codes (ps, bh, nc) int32; rep (nc, nc*c) spreads code ``k`` over
+    columns ``k*c .. k*c+c-1``; table (nc*c, D) is the block-diagonal
+    codebook (row ``k*c+j`` holds centroid ``j`` of subspace ``k`` at
+    lanes ``k*v .. k*v+v-1``); s (bh,) f32 per-head scales. The one-hot
+    of each code row times ``table`` is the dequantized row, so the MXU
+    does the lookup. Rows are head-major, so the (bh, ps) -> rows merge
+    is tile-aligned when ``ps`` is a multiple of 8.
+    Returns fp K or V rows (bh, ps, D) — never round-tripped to HBM.
     """
     ps_, bh, nc = codes.shape
-    c, v = z.shape[1], z.shape[2]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (ps_, bh, nc, c), 3)
-    oh = (codes[..., None] == iota).astype(jnp.float32)
-    ohb = jnp.transpose(oh, (2, 0, 1, 3)).reshape(nc, ps_ * bh, c)
-    sub = jax.lax.dot_general(ohb, z.astype(jnp.float32),
-                              (((2,), (1,)), ((0,), (0,))),
-                              preferred_element_type=jnp.float32)
-    rows = jnp.transpose(sub, (1, 0, 2)).reshape(ps_, bh, nc * v)
-    rows = rows * s[None, :, None]
-    return jnp.transpose(rows, (1, 0, 2))                # (bh, ps, hd)
+    ncc = rep.shape[1]
+    c = ncc // nc
+    ct = jnp.transpose(codes, (1, 0, 2)).reshape(bh * ps_, nc)
+    spread = jnp.dot(ct.astype(jnp.float32), rep,
+                     preferred_element_type=jnp.float32)   # exact: ints < c
+    j = jax.lax.broadcasted_iota(jnp.int32, (bh * ps_, ncc), 1) % c
+    oh = (spread.astype(jnp.int32) == j).astype(jnp.float32)
+    rows = jnp.dot(oh, table, preferred_element_type=jnp.float32)
+    return rows.reshape(bh, ps_, -1) * s[:, None, None]
+
+
+def _deq_operands(z):
+    """(nc, c, v) centroids -> (rep, table) operands of :func:`_deq_tile`."""
+    nc, c, v = z.shape
+    rep = (jnp.arange(nc * c)[None, :] // c
+           == jnp.arange(nc)[:, None]).astype(jnp.float32)
+    table = (z.astype(jnp.float32)[:, :, None, :]
+             * jnp.eye(nc, dtype=jnp.float32)[:, None, :, None])
+    return rep, table.reshape(nc * c, nc * v)
 
 
 def _flash_kernel_kvq(phys_ref, pos_ref, win_ref, ks_ref,   # scalar prefetch
-                      q_ref, kc_ref, vc_ref, zk_ref, zv_ref,
+                      q_ref, kc_ref, vc_ref, rep_ref, tk_ref, tv_ref,
                       sk_ref, sv_ref,                       # inputs
                       m_ref, l_ref, acc_ref, *, ps, sp):
     """Quantized-pool twin of :func:`_flash_kernel`: the DMAed page block
@@ -273,7 +291,7 @@ def _flash_kernel_kvq(phys_ref, pos_ref, win_ref, ks_ref,   # scalar prefetch
     mask = _split_masks(pos_ref[ib], win_ref[0], ks_ref[ib], kj)
 
     q = q_ref[0].astype(jnp.float32)                     # (bh, G, D)
-    k = _deq_tile(kc_ref[0].astype(jnp.int32), zk_ref[...],
+    k = _deq_tile(kc_ref[0].astype(jnp.int32), rep_ref[...], tk_ref[...],
                   sk_ref[:, 0])                          # (bh, ps, D)
     sc = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                              preferred_element_type=jnp.float32)
@@ -282,7 +300,7 @@ def _flash_kernel_kvq(phys_ref, pos_ref, win_ref, ks_ref,   # scalar prefetch
     m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1))
     p = jnp.where(mask, jnp.exp(sc - m_new[..., None]), 0.0)
     alpha = jnp.exp(m_prev - m_new)
-    v = _deq_tile(vc_ref[0].astype(jnp.int32), zv_ref[...],
+    v = _deq_tile(vc_ref[0].astype(jnp.int32), rep_ref[...], tv_ref[...],
                   sv_ref[:, 0])                          # (bh, ps, D)
     pv = jax.lax.dot_general(p, v, (((2,), (1,)), ((0,), (0,))),
                              preferred_element_type=jnp.float32)
@@ -296,9 +314,10 @@ def _splits_pallas_kvq(qg, kc_pages, vc_pages, zk, zv, sk, sv,
                        split_pages: int, block_heads: int,
                        interpret: bool = False):
     """Phase-1 triples off a QUANTIZED pool. Same grid/page-map contract
-    as :func:`_splits_pallas`; the codebook tables and per-head scales
-    ride along as small VMEM-resident operands (zk/zv whole, sk/sv tiled
-    with the kv-head grid axis)."""
+    as :func:`_splits_pallas`; the codebook tables (in the
+    :func:`_deq_operands` form) and per-head scales ride along as small
+    VMEM-resident operands (tables whole, sk/sv tiled with the kv-head
+    grid axis)."""
     b, kvh, g, d = qg.shape
     ps = kc_pages.shape[1]
     nc, c, v = zk.shape
@@ -311,7 +330,7 @@ def _splits_pallas_kvq(qg, kc_pages, vc_pages, zk, zv, sk, sv,
         return (phys_ref[ib, is_ * sp + ip], 0, ih, 0)
 
     def table_map(ib, ih, is_, ip, *_):
-        return (0, 0, 0)
+        return (0, 0)
 
     def scale_map(ib, ih, is_, ip, *_):
         return (ih, 0)
@@ -324,8 +343,9 @@ def _splits_pallas_kvq(qg, kc_pages, vc_pages, zk, zv, sk, sv,
                          lambda ib, ih, is_, ip, *_: (ib, ih, 0, 0)),
             pl.BlockSpec((1, ps, bh, nc), page_map),
             pl.BlockSpec((1, ps, bh, nc), page_map),
-            pl.BlockSpec((nc, c, v), table_map),
-            pl.BlockSpec((nc, c, v), table_map),
+            pl.BlockSpec((nc, nc * c), table_map),
+            pl.BlockSpec((nc * c, d), table_map),
+            pl.BlockSpec((nc * c, d), table_map),
             pl.BlockSpec((bh, 1), scale_map),
             pl.BlockSpec((bh, 1), scale_map),
         ],
@@ -343,11 +363,13 @@ def _splits_pallas_kvq(qg, kc_pages, vc_pages, zk, zv, sk, sv,
         jax.ShapeDtypeStruct((ns, b, kvh, g), jnp.float32),
         jax.ShapeDtypeStruct((ns, b, kvh, g, d), jnp.float32),
     ]
+    rep, table_k = _deq_operands(zk)
+    table_v = _deq_operands(zv)[1]
     kern = functools.partial(_flash_kernel_kvq, ps=ps, sp=sp)
     return pl.pallas_call(kern, grid_spec=grid_spec, out_shape=out_shape,
                           interpret=interpret)(
         phys, pos, win, ks, qg, kc_pages, vc_pages,
-        zk.astype(jnp.float32), zv.astype(jnp.float32),
+        rep, table_k, table_v,
         sk.reshape(kvh, 1).astype(jnp.float32),
         sv.reshape(kvh, 1).astype(jnp.float32))
 
@@ -470,6 +492,15 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     if s != 1:
         raise ValueError(f"flash decode is single-token (got S={s})")
     ps, kvh = k_pages.shape[1], k_pages.shape[2]
+    msize = tp_size()
+    if impl == "pallas" and msize > 1:
+        if kvh % msize:
+            require_local("flash_decode_paged")
+        # a Mosaic kernel is opaque to GSPMD: run it on each chip's
+        # kv heads (the pool is head-sharded, parallel/sharding.py)
+        return _flash_decode_tp(
+            q, k_pages, v_pages, k_new, v_new, phys, positions, window,
+            kv_start, codebook, split_pages, block_heads, interpret)
     g = h // kvh
     np_ = phys.shape[1]
     scale = d ** -0.5
@@ -483,9 +514,7 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         blk = select_blocks("flash_decode", b, np_, ps, d,
                             k_pages.dtype.itemsize, deq_itemsize=deq)
         sp = min(split_pages or blk.block_k, np_)
-        bh = min(block_heads or blk.block_n, kvh)
-        while kvh % bh:
-            bh -= 1
+        bh = flash_head_tile(kvh, block_heads or blk.block_n)
         pad = (-np_) % sp
         if pad:                       # trash-pad: kj >= NP*page >= pos
             phys = jnp.pad(phys, ((0, 0), (0, pad)),
@@ -524,6 +553,28 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
            + p_new[..., None] * v_new[:, 0, :, None, :].astype(jnp.float32))
     out = out / denom[..., None]
     return out.reshape(b, 1, h * d).astype(q.dtype)
+
+
+def _flash_decode_tp(q, k_pages, v_pages, k_new, v_new, phys, positions,
+                     window, kv_start, codebook, split_pages, block_heads,
+                     interpret):
+    """:func:`flash_decode_paged` (pallas) per ``model`` shard of heads."""
+    heads = P(None, None, "model", None)
+    cb_spec = (None if codebook is None else
+               {k: P("model") if k in ("sk", "sv") else P()
+                for k in codebook})
+
+    def local(q, kp, vp, kn, vn, phys, pos, win, ks, cb):
+        return flash_decode_paged(
+            q, kp, vp, kn, vn, phys, pos, window=win, kv_start=ks,
+            impl="pallas", codebook=cb, split_pages=split_pages,
+            block_heads=block_heads, interpret=interpret)
+    return jax.shard_map(
+        local, in_specs=(heads,) * 5 + (P(),) * 4 + (cb_spec,),
+        out_specs=P(None, None, "model"), check_vma=False)(
+        q, k_pages, v_pages, k_new, v_new, phys,
+        jnp.asarray(positions, jnp.int32), jnp.asarray(window, jnp.int32),
+        jnp.asarray(kv_start, jnp.int32), codebook)
 
 
 def resolve_flash_impl(flash: str, on_tpu: Optional[bool] = None) -> str:

@@ -17,13 +17,24 @@
 Block sizes default to the shared decode/prefill heuristic in
 :mod:`repro.kernels.tuning`; pass ``block_m``/``block_n``/``block_k``
 through ``**kw`` to override.
+
+Tensor parallelism: a Mosaic kernel is one opaque custom call that GSPMD
+cannot split, so under a mesh it would gather every operand (every LUT)
+onto every chip. When an ambient mesh (``jax.set_mesh``) has a ``model``
+axis, :func:`vq_amm` given ``tp="col"`` or ``tp="row"`` runs the kernel
+per shard under ``shard_map`` instead: column-parallel projections keep
+their N-sharded outputs, row-parallel ones sum partial outputs with one
+``psum``. A Pallas impl under such a mesh with no role, or with a split
+the axis does not divide, raises (:func:`require_local`) rather than
+gather; the XLA oracle (``impl="ref"``) is partitioned by GSPMD as usual.
 """
 from __future__ import annotations
 
-from typing import Literal
+from typing import Literal, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.similarity import Metric
 from . import ref as _ref
@@ -36,6 +47,29 @@ Impl = Literal["auto", "fused", "pallas", "ref"]
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def tp_size() -> int:
+    """Size of the ambient mesh's ``model`` axis: 1 with no mesh, and
+    inside a ``shard_map`` over that axis (each shard is already local)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if (mesh.empty or "model" not in mesh.axis_names
+            or "model" in mesh.manual_axes):
+        return 1
+    return mesh.shape["model"]
+
+
+def require_local(kernel: str) -> None:
+    """Refuse a Pallas kernel that would run whole under a ``model``
+    mesh axis: GSPMD cannot split it, so it would gather every operand
+    (every table, the whole KV pool) onto every chip."""
+    msize = tp_size()
+    if msize > 1:
+        raise NotImplementedError(
+            f"{kernel}: a Pallas kernel under a 'model' mesh axis of size "
+            f"{msize} needs a tensor-parallel split it divides (vq_amm "
+            f"tp='col'|'row'; flash decode kv heads); use impl='ref' "
+            f"here")
 
 
 def vq_assign(x: jax.Array, z: jax.Array, metric: Metric = "l2",
@@ -57,6 +91,7 @@ def vq_assign(x: jax.Array, z: jax.Array, metric: Metric = "l2",
         impl = "pallas" if _on_tpu() else "ref"
     if impl == "ref":
         return _ref.assign_ref(x, z, metric)
+    require_local("vq_assign")
     return vq_assign_pallas(x, z, metric, interpret=not _on_tpu(), **kw)
 
 
@@ -78,13 +113,15 @@ def lut_matmul(idx: jax.Array, lut: jax.Array, scale=None,
         impl = "pallas" if _on_tpu() else "ref"
     if impl == "ref":
         return _ref.lut_gemm_onehot(idx, lut, scale, out_dtype=out_dtype)
+    require_local("lut_matmul")
     return lut_gemm_pallas(idx, lut, scale, interpret=not _on_tpu(),
                            out_dtype=out_dtype, **kw)
 
 
 def vq_amm(x: jax.Array, z: jax.Array, lut: jax.Array, scale=None,
            metric: Metric = "l2", impl: Impl = "auto",
-           out_dtype=jnp.float32, **kw) -> jax.Array:
+           out_dtype=jnp.float32, tp: Optional[str] = None,
+           **kw) -> jax.Array:
     """Fused approximate matmul: CCM assignment + IMM accumulation in one.
 
     Args:
@@ -96,6 +133,9 @@ def vq_amm(x: jax.Array, z: jax.Array, lut: jax.Array, scale=None,
         unfused two-pass pipeline — kept as the fused kernel's measurable
         baseline; "ref" forces the oracle.
       out_dtype: accumulator/output dtype.
+      tp: tensor-parallel role of the projection under a ``model`` mesh
+        axis — "col" (LUT sharded on N) or "row" (LUT, centroids and x
+        sharded on subspaces). A Pallas impl under that axis needs one.
       **kw: block-size overrides (``block_m``/``block_n``/``block_k``).
 
     Returns: (M, N) ≈ ``x.reshape(M, K) @ W`` for the W the LUT encodes.
@@ -104,6 +144,11 @@ def vq_amm(x: jax.Array, z: jax.Array, lut: jax.Array, scale=None,
         impl = "fused" if _on_tpu() else "ref"
     if impl == "ref":
         return _ref.vq_amm_ref(x, z, lut, scale, metric, out_dtype=out_dtype)
+    msize = tp_size()
+    split = {"col": lut.shape[2], "row": lut.shape[0]}.get(tp)
+    if msize > 1 and split and split % msize == 0:
+        return _vq_amm_tp(x, z, lut, scale, metric, impl, out_dtype, tp, kw)
+    require_local("vq_amm")
     if impl == "pallas":                 # two-pass baseline
         akw = {k: v for k, v in kw.items() if k in ("block_m", "block_k")}
         idx = vq_assign_pallas(x, z, metric, interpret=not _on_tpu(), **akw)
@@ -111,3 +156,28 @@ def vq_amm(x: jax.Array, z: jax.Array, lut: jax.Array, scale=None,
                                out_dtype=out_dtype, **kw)
     return vq_amm_pallas(x, z, lut, scale, metric,
                          interpret=not _on_tpu(), out_dtype=out_dtype, **kw)
+
+
+def _vq_amm_tp(x, z, lut, scale, metric, impl, out_dtype, tp, kw):
+    """:func:`vq_amm` with the kernel run per ``model`` shard.
+
+    Row-parallel shards psum their accumulators before the int8 scale is
+    applied: integer-valued partial sums add exactly in f32, so the
+    result is bit-identical to the unsharded kernel."""
+    def local(x, z, lut, *s):
+        return vq_amm(x, z, lut, s[0] if s else None, metric, impl,
+                      out_dtype, **kw)
+    if tp == "col":
+        args = (x, z, lut) + (() if scale is None else (scale,))
+        specs = (P(), P(), P(None, None, "model"), P("model"))
+        return jax.shard_map(local, in_specs=specs[:len(args)],
+                             out_specs=P(None, "model"),
+                             check_vma=False)(*args)
+    out = jax.shard_map(
+        lambda *a: jax.lax.psum(local(*a), "model"),
+        in_specs=(P(None, "model", None), P("model", None, None),
+                  P("model", None, None)),
+        out_specs=P(), check_vma=False)(x, z, lut)
+    if scale is not None:
+        out = out * scale[None, :].astype(out_dtype)
+    return out

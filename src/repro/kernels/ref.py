@@ -18,12 +18,19 @@ def assign_ref(x: jax.Array, z: jax.Array, metric: Metric = "l2") -> jax.Array:
     x : (M, nc, v)   input sub-vectors
     z : (nc, c, v)   centroids
     -> (M, nc) int32 indices
+
+    Distances are computed in fp32 whatever the input dtype, as the
+    kernels compute them (at full MXU precision on TPU, so f32 inputs
+    are not rounded to bf16 here).
     """
+    x = x.astype(jnp.float32)
+    z = z.astype(jnp.float32)
     if metric == "l2":
         # batched MXU form: ||x||^2 - 2<x,z> + ||z||^2
         x2 = jnp.sum(x * x, axis=-1)[..., None]                # (M, nc, 1)
         z2 = jnp.sum(z * z, axis=-1)[None]                     # (1, nc, c)
-        xz = jnp.einsum("mkv,kcv->mkc", x, z)                  # (M, nc, c)
+        xz = jnp.einsum("mkv,kcv->mkc", x, z,
+                        precision=jax.lax.Precision.HIGHEST)   # (M, nc, c)
         d = x2 - 2.0 * xz + z2
     else:
         diff = jnp.abs(x[:, :, None, :] - z[None])             # (M, nc, c, v)

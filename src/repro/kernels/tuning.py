@@ -26,6 +26,12 @@ from typing import Optional
 # input blocks and holds the fp32 accumulator too.
 _VMEM_BUDGET = 4 * 1024 * 1024
 
+# lane width of a TPU vreg: the minor dim of a block must be a multiple
+# of it or the whole array dim
+_INDEX_LANES = 128
+# rows x subspaces of one assign / lut_gemm tile
+_ROWS_X_SUBSPACES = 8192
+
 
 @dataclasses.dataclass(frozen=True)
 class BlockConfig:
@@ -81,6 +87,20 @@ def fit_vmem(block_n: int, block_k: int, c: int,
     return bn, bk
 
 
+def flash_head_tile(kvh: int, cap: int) -> int:
+    """kv-head tile of the paged flash-decode kernel for ``kvh`` heads.
+
+    The tile is the second-minor dim of the K/V page block ``(page,
+    heads, head_dim)``, which the TPU compiler accepts only as a multiple
+    of 8 or as all ``kvh`` heads. Returns the largest such divisor of
+    ``kvh`` that is <= ``cap`` (the VMEM bound from :func:`select_blocks`),
+    else the smallest legal tile.
+    """
+    legal = [d for d in range(8, kvh, 8) if kvh % d == 0] + [kvh]
+    fit = [d for d in legal if d <= cap]
+    return max(fit) if fit else min(legal)
+
+
 def select_blocks(kind: str, m: int, nc: int, c: int,
                   n: Optional[int] = None,
                   itemsize: int = 4,
@@ -102,8 +122,9 @@ def select_blocks(kind: str, m: int, nc: int, c: int,
     For "flash_decode" the axes are reinterpreted for the paged
     attention kernel: m = batch slots, nc = pages per slot, c = page
     size (tokens), n = head_dim, itemsize = KV pool bytes/elt. The
-    returned block_n is the kv-head tile (halved until the double-
-    buffered K+V page tile fits VMEM) and block_k the pages per split.
+    returned block_n is the kv-head tile bound (halved until the double-
+    buffered K+V page tile fits VMEM; :func:`flash_head_tile` turns it
+    into a tile the compiler accepts) and block_k the pages per split.
     """
     cfg = _TABLE[regime(m)][kind]
     if kind == "flash_decode":
@@ -118,6 +139,14 @@ def select_blocks(kind: str, m: int, nc: int, c: int,
         return BlockConfig(cfg.block_m, bh, sp)
     bm = min(cfg.block_m, max(m, 1))
     bk = min(cfg.block_k, max(nc, 1))
+    if kind in ("assign", "lut_gemm"):
+        # the (bm, bk) index block carries bk in its minor (lane) dim,
+        # which the TPU compiler takes only as a multiple of 128 or as
+        # all nc subspaces (the wrappers pad nc up to a multiple of bk)
+        bk = min(max(nc, 1), _INDEX_LANES)
+        # the (bm, bk, c) distance / one-hot intermediate pads c up to
+        # 128 lanes in VMEM: bound bm * bk to keep it near 4 MiB
+        bm = min(bm, max(8, _ROWS_X_SUBSPACES // bk))
     if kind == "assign":
         return BlockConfig(bm, 0, bk)
     bn, bk = fit_vmem(cfg.block_n, bk, c, itemsize)

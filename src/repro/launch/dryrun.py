@@ -15,7 +15,7 @@ from repro.configs import ARCH_NAMES, get_config          # noqa: E402
 from repro.core.lut import QuantConfig                     # noqa: E402
 from repro.launch import roofline as rl                    # noqa: E402
 from repro.launch.mesh import (data_axes,                  # noqa: E402
-                               make_production_mesh, mesh_context)
+                               make_production_mesh)
 from repro.launch.specs import (SHAPES, cell_is_runnable,   # noqa: E402
                                 serve_input_specs, train_input_specs)
 from repro.models.model import Model                        # noqa: E402
@@ -89,7 +89,7 @@ def run_cell(arch: str, shape_name: str, mesh, quant: str = "lut",
         step_fn = make_train_step(model, qc, tc, stage=3)
         jitted = jax.jit(step_fn, in_shardings=in_specs,
                          out_shardings=out_specs)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(p_s, o_s, b_s, step_s)
     else:
         specs = serve_input_specs(model, qc, case)
@@ -117,7 +117,7 @@ def run_cell(arch: str, shape_name: str, mesh, quant: str = "lut",
         out_specs = (_shard(mesh, logits_spec), _shard(mesh, cache_spec))
         jitted = jax.jit(fn, in_shardings=in_specs, out_shardings=out_specs,
                          donate_argnums=(2,))
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(p_s, in2_s, cache_s)
     t_lower = time.perf_counter() - t0
 

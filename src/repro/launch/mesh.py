@@ -13,26 +13,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # jax < 0.6 has no jax.sharding.AxisType (meshes are implicitly Auto);
-    # newer versions want it spelled out.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
-
-
-def mesh_context(mesh):
-    """Ambient-mesh context manager across jax versions.
-
-    jax >= 0.6 spells it ``jax.set_mesh``; on 0.4.x the ``Mesh`` object is
-    itself a context manager with the same effect for explicitly-sharded
-    ``jit.lower`` calls.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

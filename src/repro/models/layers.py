@@ -46,9 +46,11 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
-def proj(p: Params, x: jax.Array, qc: QuantConfig) -> Tuple[jax.Array, jax.Array]:
-    """One LutLinear projection (out, recon)."""
-    return lut_linear_apply(p, x, qc)
+def proj(p: Params, x: jax.Array, qc: QuantConfig,
+         tp: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
+    """One LutLinear projection (out, recon); ``tp`` is its
+    tensor-parallel role, "col" or "row" (parallel/sharding.py)."""
+    return lut_linear_apply(p, x, qc, tp)
 
 
 def init_proj(key, k, n, qc: QuantConfig, bias=False, dtype=jnp.float32):
@@ -501,9 +503,9 @@ def attention(p: Params, x: jax.Array, cfg, qc: QuantConfig,
     b, s, _ = x.shape
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     xn = rms_norm(x, p["norm"], cfg.norm_eps)
-    q, r1 = proj(p["wq"], xn, qc)
-    k, r2 = proj(p["wk"], xn, qc)
-    v, r3 = proj(p["wv"], xn, qc)
+    q, r1 = proj(p["wq"], xn, qc, "col")
+    k, r2 = proj(p["wk"], xn, qc, "col")
+    v, r3 = proj(p["wv"], xn, qc, "col")
     qo = jnp.asarray(q_offset)
     if qo.ndim == 0:
         positions = (jnp.arange(s) + q_offset)[None, :]          # (1, S)
@@ -551,7 +553,7 @@ def attention(p: Params, x: jax.Array, cfg, qc: QuantConfig,
                                cache["v"].astype(x.dtype),
                                k.astype(x.dtype), v.astype(x.dtype),
                                q_offset, window)
-        out, r4 = proj(p["wo"], out, qc)
+        out, r4 = proj(p["wo"], out, qc, "row")
         if cb is not None:      # quantized pool: slab must stay fp
             slab = {"k": k, "v": v}
         else:
@@ -577,7 +579,7 @@ def attention(p: Params, x: jax.Array, cfg, qc: QuantConfig,
             and prefix_len == 0 and cfg.head_layout != "hd"
             and _trivial_start(kv_start)):
         out = _sdpa_local(q, k_fresh, v_fresh, window).reshape(b, s, h * hd)
-        out, r4 = proj(p["wo"], out, qc)
+        out, r4 = proj(p["wo"], out, qc, "row")
         return out, r1 + r2 + r3 + r4, new_cache
 
     # decode (s==1): the full score row is tiny — use the naive path. The
@@ -605,7 +607,7 @@ def attention(p: Params, x: jax.Array, cfg, qc: QuantConfig,
         if apply_u:                            # all-to-all back to hd-shard
             out = jax.lax.with_sharding_constraint(out, specs["out"])
         out = out.reshape(b, s, h * hd)
-    out, r4 = proj(p["wo"], out, qc)
+    out, r4 = proj(p["wo"], out, qc, "row")
     return out, r1 + r2 + r3 + r4, new_cache
 
 
@@ -626,7 +628,7 @@ def init_mlp(key, d: int, f: int, cfg, qc: QuantConfig, dtype):
 def mlp(p: Params, x: jax.Array, cfg, qc: QuantConfig):
     """Pre-norm SwiGLU MLP. Returns (out, recon)."""
     xn = rms_norm(x, p["norm"], cfg.norm_eps)
-    g, r1 = proj(p["wg"], xn, qc)
-    u, r2 = proj(p["wu"], xn, qc)
-    d_, r3 = proj(p["wd"], jax.nn.silu(g) * u, qc)
+    g, r1 = proj(p["wg"], xn, qc, "col")
+    u, r2 = proj(p["wu"], xn, qc, "col")
+    d_, r3 = proj(p["wd"], jax.nn.silu(g) * u, qc, "row")
     return d_, r1 + r2 + r3

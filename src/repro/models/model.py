@@ -61,6 +61,12 @@ class Model:
         return block
 
     def init(self, key: jax.Array, qc: QuantConfig = DENSE) -> Params:
+        """Random params from ``key``. Jitted, so each stacked weight is
+        drawn and cast to ``cfg.dtype`` in one fused program (no fp32
+        copy of the full stack is ever resident)."""
+        return jax.jit(self._init, static_argnums=1)(key, qc)
+
+    def _init(self, key: jax.Array, qc: QuantConfig) -> Params:
         cfg, dtype = self.cfg, self.dtype
         ke, kb, kh, ks = jax.random.split(key, 4)
         params: Params = {"final_norm": jnp.zeros((cfg.d_model,), dtype)}

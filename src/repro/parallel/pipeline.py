@@ -19,17 +19,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    # jax >= 0.6 exposes jax.shard_map (check_vma); older versions ship it
-    # under jax.experimental.shard_map with the check_rep spelling.
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 def pipeline(block_fn: Callable, n_stages: int, n_micro: int,
              axis: str = "stage"):
     """Build a pipelined forward: f(stage_params, x_micro) -> y_micro.
@@ -90,9 +79,9 @@ def run_pipeline(mesh: Mesh, block_fn: Callable, stage_params, x,
     x_micro = x.reshape(n_micro, b // n_micro, *x.shape[1:])
 
     staged = pipeline(block_fn, n_stages, n_micro, axis)
-    fn = _shard_map(
-        staged, mesh,
+    fn = jax.shard_map(
+        staged, mesh=mesh,
         in_specs=(P(axis), P()),            # params sharded, x replicated
-        out_specs=P())
+        out_specs=P(), check_vma=False)
     y_micro = fn(stage_params, x_micro)
     return y_micro.reshape(b, *x.shape[1:])

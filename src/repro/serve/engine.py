@@ -53,7 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.kv_codebook import KVCodebook
-from repro.core.lut import DENSE, QuantConfig
+from repro.core.lut import DENSE, QuantConfig, missing_luts
 from repro.obs import Obs, safe_ratio
 
 from .kv_cache import PagedKVCache, PagePoolExhausted
@@ -274,6 +274,14 @@ class Engine:
                  = DEFAULT_DEGRADATION,
                  kv_codebook: Optional[KVCodebook] = None,
                  obs: Optional[Obs] = None):
+        if qc.mode == "lut_infer":
+            missing = missing_luts(params)
+            if missing:
+                # lut_linear_apply would rebuild each table on every call
+                raise ValueError(
+                    f"lut_infer serving needs precomputed tables "
+                    f"(repro.core.precompute_model); {len(missing)} "
+                    f"layer(s) have none, e.g. {missing[0]}")
         self.model = model
         self.params = params
         self.qc = qc
@@ -487,8 +495,7 @@ class Engine:
         (lets in-model ``with_sharding_constraint`` hooks see the mesh)."""
         if self.mesh is None:
             return contextlib.nullcontext()
-        from repro.launch.mesh import mesh_context
-        return mesh_context(self.mesh)
+        return jax.set_mesh(self.mesh)
 
     # ------------------------------------------------------------------
     # host transfers

@@ -225,8 +225,7 @@ def test_seeded_gather_over_budget():
 
 
 def test_seeded_f64_detected():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64():
         closed = jax.make_jaxpr(
             lambda x: x.astype(jnp.float64) * 2.0)(jnp.ones((4,)))
     fs = check_invariants(closed, name="fixture")

@@ -89,7 +89,7 @@ def test_split_reduction_count_and_order_invariant(seed, n, pa, pb):
         np.testing.assert_allclose(np.asarray(acc_t) / np.asarray(l_t),
                                    oracle, rtol=1e-4, atol=1e-5)
     else:                                          # identity, not NaN
-        assert float(l_t) == 0.0 and float(m_t) == NEG_INF
+        assert float(l_t) == 0.0 and float(m_t) == np.float32(NEG_INF)
 
 
 @settings(max_examples=30, deadline=None)

@@ -393,6 +393,19 @@ def test_paged_parity_lut_infer():
     _paged_parity("qwen1.5-4b", qc_i, mk)
 
 
+def test_engine_refuses_lut_infer_without_tables():
+    """Serving never falls back to rebuilding each table per call: params
+    without precomputed LUTs are refused until precompute_model runs."""
+    cfg = get_smoke_config("qwen1.5-4b")
+    m = Model(cfg)
+    qc_i = QuantConfig(mode="lut_infer", v=4, c=8, impl="ref")
+    params = m.init(KEY, QuantConfig(mode="lut_train", v=4, c=8))
+    with pytest.raises(ValueError, match="precompute_model"):
+        Engine(m, params, qc_i, batch_size=2, max_seq=32)
+    Engine(m, precompute_model(params, qc_i), qc_i, batch_size=2,
+           max_seq=32)
+
+
 def test_lut_infer_engine_matches_dense_cache_engine():
     """End-to-end: continuous engine (paged) == batch engine (dense cache)
     for greedy decoding on the lut_infer path."""

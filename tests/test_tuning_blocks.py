@@ -95,9 +95,9 @@ def _case(seed, slots, np_, ps, kvh, g, d, positions):
 
 
 @pytest.mark.parametrize("kvh,g,block_heads", [
-    (6, 2, 4),    # 4 does not divide 6: kernel degrades the tile to 3
-    (7, 1, None), # prime head count: the full-kvh tile (7) divides
-    (7, 1, 4),    # prime + non-divisor request: degrades to 1
+    (6, 2, 4),    # no multiple of 8 divides 6: the tile is all 6 heads
+    (7, 1, None), # prime head count: the full-kvh tile (7)
+    (7, 1, 4),    # prime + a request below kvh: still the full tile
 ])
 def test_non_pow2_head_count_parity(kvh, g, block_heads):
     args = _case(3, 2, 4, 8, kvh, g, 16, [5, 13])
