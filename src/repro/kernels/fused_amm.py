@@ -135,6 +135,7 @@ def vq_amm_pallas(x: jax.Array, z: jax.Array, lut: jax.Array,
             out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
             interpret=interpret,
+            name="vq_amm_pallas",
         )(x, z, lut)
     if scale is not None:
         out = out * scale[None, :].astype(out_dtype)

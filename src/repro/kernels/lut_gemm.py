@@ -98,6 +98,7 @@ def lut_gemm_pallas(idx: jax.Array, lut: jax.Array,
             out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
             interpret=interpret,
+            name="lut_gemm_pallas",
         )(idx, lut)
     if scale is not None:
         out = out * scale[None, :].astype(out_dtype)

@@ -73,8 +73,11 @@ class Obs:
       context manager and no histograms are touched (the < 5% overhead
       micro-benchmark in ``serve_bench`` measures this path).
     * ``jax_annotations`` — additionally wrap phases in
-      ``jax.profiler.TraceAnnotation`` for XLA profiles (off by
-      default; purely additive).
+      ``jax.profiler.TraceAnnotation`` named ``engine.<phase>``, so
+      they sit on the device profile's clock beside ``XLA Modules`` (off
+      by default; purely additive). :meth:`span` opens a profiler-only
+      span, and the engine computes span arguments (``set_metadata``)
+      only when this is on.
     """
 
     def __init__(self, metrics: Optional[Registry] = None,
@@ -109,11 +112,19 @@ class Obs:
 
     def phase(self, name: str):
         """Context manager timing one step phase (no-op when inactive)."""
-        if not (self.timing or self.tracer.enabled):
-            return NULL_CTX
         if self.jax_annotations:
             return _AnnotatedPhase(self, name)
+        if not (self.timing or self.tracer.enabled):
+            return NULL_CTX
         return _Phase(self, name)
+
+    def span(self, name: str):
+        """Profiler-only span ``engine.<name>`` (no histogram, no trace
+        event of this bundle's tracer): a ``TraceAnnotation`` when
+        ``jax_annotations`` is on, else the shared null context."""
+        if self.jax_annotations:
+            return jax_annotation("engine." + name)
+        return NULL_CTX
 
     def annotate(self, name: str, **args) -> None:
         """Instant annotation event on this replica's trace track
@@ -131,14 +142,20 @@ class Obs:
 
 
 class _AnnotatedPhase(_Phase):
-    """_Phase that also enters a ``jax.profiler.TraceAnnotation``."""
+    """_Phase that also enters a ``jax.profiler.TraceAnnotation`` named
+    ``engine.<phase>``, which may carry arguments."""
 
     __slots__ = ("_ann",)
 
     def __enter__(self):
-        self._ann = jax_annotation(self.name)
+        self._ann = jax_annotation("engine." + self.name)
         self._ann.__enter__()
         return super().__enter__()
+
+    def set_metadata(self, **args) -> None:
+        """Attach host ints to the profiler span (read back as the
+        event's stats), as ``TraceAnnotation.set_metadata`` does."""
+        self._ann.set_metadata(**args)
 
     def __exit__(self, *exc):
         super().__exit__(*exc)

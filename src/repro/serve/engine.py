@@ -178,6 +178,30 @@ def _with_argmax(logits: jax.Array, kv):
     return logits, jnp.argmax(logits, axis=-1).astype(jnp.int32), kv
 
 
+def step_programs(model, qc: QuantConfig, act_sharding=None):
+    """The paged engine's step programs as named functions: (prefill_chunk,
+    decode_step, verify_step).
+
+    Jitted under these names, each shows in a profile as
+    ``PjitFunction(<name>)`` on the host and as ``jit_<name>(...)`` on
+    the device's ``XLA Modules`` line. ``act_sharding`` is the sharded
+    engine's replicated activation placement (``Model.prefill_paged``)."""
+
+    def prefill_chunk(p, t, kv, pt, slot, pos, valid):
+        return model.prefill_paged(p, t, kv, pt, slot, pos, valid, qc,
+                                   act_sharding=act_sharding)
+
+    def decode_step(p, t, kv, pt, positions):
+        return model.decode_paged(p, t, kv, pt, positions, qc,
+                                  act_sharding=act_sharding)
+
+    def verify_step(p, t, kv, pt, pos, nl):
+        return _with_argmax(*model.verify_paged(
+            p, t, kv, pt, pos, nl, qc, act_sharding=act_sharding))
+
+    return prefill_chunk, decode_step, verify_step
+
+
 def _sample_tokens(key: jax.Array, logits: jax.Array,
                    temps: Optional[jax.Array], slot_ids: Sequence[int]):
     """Shared sampling helper. Returns (new_key, tokens (B,) int32).
@@ -368,18 +392,10 @@ class Engine:
         self.mesh = mesh
         self._table_sharding = None
         if mesh is None:
-            self._jit_prefill = jax.jit(
-                lambda p, t, kv, pt, slot, pos, valid: model.prefill_paged(
-                    p, t, kv, pt, slot, pos, valid, qc),
-                donate_argnums=(2,))
-            self._jit_decode = jax.jit(
-                lambda p, t, kv, pt, positions: model.decode_paged(
-                    p, t, kv, pt, positions, qc),
-                donate_argnums=(2,))
-            self._jit_verify = jax.jit(
-                lambda p, t, kv, pt, pos, nl: _with_argmax(
-                    *model.verify_paged(p, t, kv, pt, pos, nl, qc)),
-                donate_argnums=(2,))
+            prefill, decode, verify = step_programs(model, qc)
+            self._jit_prefill = jax.jit(prefill, donate_argnums=(2,))
+            self._jit_decode = jax.jit(decode, donate_argnums=(2,))
+            self._jit_verify = jax.jit(verify, donate_argnums=(2,))
         else:
             self._init_sharded(mesh)
 
@@ -390,9 +406,10 @@ class Engine:
         # constant; greedy (temps=None) and temperature batches are two
         # shape classes of the same jit.
         nslots = self.num_slots
-        self._jit_sample = jax.jit(
-            lambda key, logits, temps: _sample_tokens(
-                key, logits, temps, range(nslots)))
+
+        def sample_tokens(key, logits, temps):
+            return _sample_tokens(key, logits, temps, range(nslots))
+        self._jit_sample = jax.jit(sample_tokens)
         # Host-transfer accounting: every per-step device->host read in
         # the serving loop goes through _device_read, which bumps this.
         self._c_device_reads = met.counter("engine.device_reads",
@@ -468,24 +485,21 @@ class Engine:
         # the step methods wrap in _mesh_scope() (the ambient mesh the
         # in-model with_sharding_constraint hooks need); scoping the jit
         # construction here would be inert.
+        prefill, decode, verify = step_programs(model, qc, act_sharding=repl)
         self._jit_prefill = jax.jit(
-            lambda p, t, kv, pt, slot, pos, valid: model.prefill_paged(
-                p, t, kv, pt, slot, pos, valid, qc, act_sharding=repl),
+            prefill,
             in_shardings=(pshard, repl, cshard, repl, repl, repl, repl),
             out_shardings=(repl, cshard),
             donate_argnums=(2,))
         self._jit_decode = jax.jit(
-            lambda p, t, kv, pt, positions: model.decode_paged(
-                p, t, kv, pt, positions, qc, act_sharding=repl),
+            decode,
             in_shardings=(pshard, repl, cshard, repl, repl),
             out_shardings=(repl, cshard),
             donate_argnums=(2,))
         self._param_sharding = pshard
         self._cache_sharding = cshard
         self._jit_verify = jax.jit(
-            lambda p, t, kv, pt, pos, nl: _with_argmax(
-                *model.verify_paged(p, t, kv, pt, pos, nl, qc,
-                                    act_sharding=repl)),
+            verify,
             in_shardings=(pshard, repl, cshard, repl, repl, repl),
             out_shardings=(repl, repl, cshard),
             donate_argnums=(2,))
@@ -756,49 +770,60 @@ class Engine:
         drops speculative lookahead, mode 2 shrinks the prefill budget,
         mode 3 stops admitting (docs/robustness.md).
         Returns False when there was nothing to do.
+
+        With ``Obs(jax_annotations=True)`` the step is the profiler span
+        ``engine.step``, carrying ``step``, ``decode_slots``,
+        ``prefill_tokens`` and ``live_pages`` (docs/observability.md).
         """
-        self._update_degradation()
-        self._c_mode[self.mode].inc()
         obs = self.obs
-        if obs.active:        # pool gauges: host ints, but O(pages) scans
-            self._g_pool_bytes.set(float(self.kv.live_bytes))
-            self._g_pressure.set(self.pressure)
-            self._g_mode.set(float(self.mode))
-            obs.track("pool.pressure", self.pressure)
-        with obs.phase("admit"):
-            for req in self.scheduler.expire_deadlines(self.step_count,
-                                                       self.kv):
-                log.info("request expired past deadline_steps=%s",
-                         req.deadline_steps)
-                _observe_request(obs, req)
-            for s in self.scheduler.slots:   # expiry may have freed lanes
-                if s.free:
-                    self._set_slot_temp(s.idx, 0.0)
-            # Admission stops at the top of the ladder — but never on an
-            # idle engine (nothing running = nothing will release pages,
-            # so waiting would deadlock; pressure on an idle pool is ~0
-            # anyway unless pages are held externally, and then admit()
-            # simply waits).
-            if (self.mode < MODE_STOP_ADMIT
-                    or not self.scheduler.occupied_slots()):
-                for slot in self.scheduler.admit(self.kv):
-                    self._set_slot_temp(slot.idx, slot.req.temperature)
-                    self._c_prompt.inc(slot.prefill_len)
-                    self._c_cached.inc(slot.pos)  # admission: pos = matched
-        progressed = False
-        slot = self.scheduler.next_prefill()
-        if slot is not None:
-            with obs.phase("prefill_chunk"):
-                self._prefill_chunk_step(slot)
-            progressed = True
-        if self.scheduler.decode_slots():
-            if self.spec is not None and self.mode < MODE_NO_SPEC:
-                self._spec_decode_step()
-            else:
-                self._decode_step()
-            progressed = True
-        self.step_count += 1
+        with obs.span("step") as span:
+            self._update_degradation()
+            self._c_mode[self.mode].inc()
+            if obs.active:    # pool gauges: host ints, but O(pages) scans
+                self._g_pool_bytes.set(float(self.kv.live_bytes))
+                self._g_pressure.set(self.pressure)
+                self._g_mode.set(float(self.mode))
+                obs.track("pool.pressure", self.pressure)
+            with obs.phase("admit"):
+                self._admit()
+            progressed = False
+            prefill_tokens = decode_slots = 0
+            slot = self.scheduler.next_prefill()
+            if slot is not None:
+                prefill_tokens = self._prefill_chunk_step(slot)
+                progressed = True
+            if self.scheduler.decode_slots():
+                if self.spec is not None and self.mode < MODE_NO_SPEC:
+                    decode_slots = self._spec_decode_step()
+                else:
+                    decode_slots = self._decode_step()
+                progressed = True
+            if obs.jax_annotations:
+                span.set_metadata(step=self.step_count, decode_slots=decode_slots,
+                          prefill_tokens=prefill_tokens,
+                          live_pages=self.kv.live_pages)
+            self.step_count += 1
         return progressed
+
+    def _admit(self) -> None:
+        """Expire requests past their deadline, then admit waiting ones
+        into free slots (the step's ``admit`` phase)."""
+        for req in self.scheduler.expire_deadlines(self.step_count, self.kv):
+            log.info("request expired past deadline_steps=%s",
+                     req.deadline_steps)
+            _observe_request(self.obs, req)
+        for s in self.scheduler.slots:       # expiry may have freed lanes
+            if s.free:
+                self._set_slot_temp(s.idx, 0.0)
+        # Admission stops at the top of the ladder — but never on an idle
+        # engine (nothing running = nothing will release pages, so waiting
+        # would deadlock; pressure on an idle pool is ~0 anyway unless
+        # pages are held externally, and then admit() simply waits).
+        if self.mode < MODE_STOP_ADMIT or not self.scheduler.occupied_slots():
+            for slot in self.scheduler.admit(self.kv):
+                self._set_slot_temp(slot.idx, slot.req.temperature)
+                self._c_prompt.inc(slot.prefill_len)
+                self._c_cached.inc(slot.pos)      # admission: pos = matched
 
     # ------------------------------------------------------------------
     # internals
@@ -844,7 +869,9 @@ class Engine:
         return max(self.degradation.min_chunk,
                    self.prefill_chunk // self.degradation.chunk_divisor)
 
-    def _prefill_chunk_step(self, slot) -> None:
+    def _prefill_chunk_step(self, slot) -> int:
+        """Run one prompt chunk of ``slot``; returns its token count."""
+        obs = self.obs
         c = self.prefill_chunk           # static compiled width, never shrunk
         chunk = self.scheduler.prompt_chunk(
             slot, min(c, self.prefill_budget))
@@ -854,24 +881,33 @@ class Engine:
         toks = np.zeros((1, c), np.int32)
         toks[0, :valid] = chunk
         with self._mesh_scope():
-            logits, self.kv.data = self._jit_prefill(
-                self.params, jnp.asarray(toks), self.kv.data,
-                self.kv.table_device(self._table_sharding), _i32(slot.idx),
-                _i32(slot.pos), _i32(valid))
+            with obs.span("upload"):
+                toks_d = jnp.asarray(toks)
+                table = self.kv.table_device(self._table_sharding)
+                scalars = _i32(slot.idx), _i32(slot.pos), _i32(valid)
+            with obs.phase("prefill_chunk") as span:
+                if obs.jax_annotations:
+                    span.set_metadata(req=slot.req._seq, start=slot.pos,
+                              tokens=valid)
+                logits, self.kv.data = self._jit_prefill(
+                    self.params, toks_d, self.kv.data, table, *scalars)
         slot.pos += valid
         self._c_prefilled.inc(valid)
         # index the prompt pages this chunk completed: from here on other
         # requests sharing the prefix can map them instead of recomputing
         self.kv.register_prefix(slot.idx, slot.prompt, slot.pos)
         if slot.pos < slot.prefill_len:
-            return
+            return valid
         req = slot.req
-        temps = (jnp.asarray([req.temperature], jnp.float32)
-                 if req.temperature > 0.0 else None)
+        temps = None
+        if req.temperature > 0.0:
+            with obs.span("upload"):
+                temps = jnp.asarray([req.temperature], jnp.float32)
         tok = int(self._device_read(
             self._sample(logits, temps, [slot.idx]))[0])
         self.scheduler.finish_prefill(slot, tok)
         self._record_token(slot, tok)
+        return valid
 
     def _grow_or_shed(self, s) -> None:
         """Reserve the page covering slot ``s``'s next write position,
@@ -895,7 +931,9 @@ class Engine:
                 self.scheduler.preempt(s, self.kv)
                 self._set_slot_temp(s.idx, 0.0)
 
-    def _decode_step(self) -> None:
+    def _decode_step(self) -> int:
+        """One decode step over every decoding slot; returns how many
+        slots decoded."""
         for s in list(self.scheduler.decode_slots()):
             if s.phase is not SlotPhase.DECODE:
                 continue          # preempted by an earlier ensure this loop
@@ -903,7 +941,7 @@ class Engine:
             self._grow_or_shed(s)
         dslots = self.scheduler.decode_slots()  # preemption may have culled
         if not dslots:
-            return
+            return 0
         b = self.num_slots
         toks = np.zeros((b, 1), np.int32)
         # -1 marks lanes that are NOT decoding this step (free slots and
@@ -913,22 +951,29 @@ class Engine:
         for s in dslots:
             toks[s.idx, 0] = s.next_token
             positions[s.idx] = s.pos
-        # device-resident per-slot temps: refreshed on admission/eviction,
-        # NOT rebuilt and re-uploaded every decode step
-        temps = self._decode_temps()
+        obs = self.obs
         with self._mesh_scope():
-            with self.obs.phase("decode"):
+            with obs.span("upload"):
+                toks_d = jnp.asarray(toks)
+                table = self.kv.table_device(self._table_sharding)
+                pos_d = jnp.asarray(positions)
+                # device-resident per-slot temps: refreshed on admission
+                # or eviction, NOT rebuilt and re-uploaded every step
+                temps = self._decode_temps()
+            with obs.phase("decode") as span:
+                if obs.jax_annotations:
+                    span.set_metadata(slots=len(dslots),
+                              positions=sum(s.pos for s in dslots))
                 logits, self.kv.data = self._jit_decode(
-                    self.params, jnp.asarray(toks), self.kv.data,
-                    self.kv.table_device(self._table_sharding),
-                    jnp.asarray(positions))
-            with self.obs.phase("sample"):
+                    self.params, toks_d, self.kv.data, table, pos_d)
+            with obs.phase("sample"):
                 self.key, nxt_dev = self._jit_sample(self.key, logits,
                                                      temps)
         nxt = self._device_read(nxt_dev)
         for s in dslots:
             s.pos += 1
             self._record_token(s, int(nxt[s.idx]))
+        return len(dslots)
 
     # ------------------------------------------------------------------
     # speculative decoding (docs/speculative.md)
@@ -947,8 +992,9 @@ class Engine:
         return safe_ratio(self._c_spec_emitted.value,
                           self._c_spec_rounds.value)
 
-    def _spec_decode_step(self) -> None:
-        """One draft/verify round over every decoding slot.
+    def _spec_decode_step(self) -> int:
+        """One draft/verify round over every decoding slot; returns how
+        many slots it verified.
 
         Replaces :meth:`_decode_step` when ``spec_decode`` is configured:
         the drafter proposes up to ``k`` tokens per slot, ONE
@@ -969,7 +1015,7 @@ class Engine:
             self._grow_or_shed(s)
         dslots = self.scheduler.decode_slots()
         if not dslots:
-            return
+            return 0
         k = self.spec.k
         # Draft lookahead is capped by sequence room and the slot's
         # remaining generation budget (no proposal that could never be
@@ -1004,11 +1050,13 @@ class Engine:
             posv[s.idx] = s.pos
             nlive[s.idx] = n + 1
         with self._mesh_scope():
+            with self.obs.span("upload"):
+                toks_d = jnp.asarray(toks)
+                table = self.kv.table_device(self._table_sharding)
+                rows = jnp.asarray(posv), jnp.asarray(nlive)
             with self.obs.phase("verify"):
                 logits, ids, self.kv.data = self._jit_verify(
-                    self.params, jnp.asarray(toks), self.kv.data,
-                    self.kv.table_device(self._table_sharding),
-                    jnp.asarray(posv), jnp.asarray(nlive))
+                    self.params, toks_d, self.kv.data, table, *rows)
         # all-greedy rounds pull only the (B, k+1) argmax ids; the full
         # logits tensor rides the SAME single transfer only when a
         # temperature slot needs distributions for rejection sampling
@@ -1043,6 +1091,7 @@ class Engine:
                 # fires — and only ever releases fresh refcount-1 draft
                 # pages — when rejection left a page boundary behind.
                 self.kv.trim(s.idx, s.pos + 1)
+        return len(dslots)
 
     def _record_token(self, slot, tok: int) -> None:
         """Append a sampled token and apply the eviction rules."""

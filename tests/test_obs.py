@@ -476,3 +476,110 @@ def test_recompile_guard_with_obs_on(qwen):
     assert report.ok, "\n".join(f.render() for f in report.findings)
     assert report.steady_events == []
     assert len(obs.tracer) > 0               # tracing really was on
+
+
+# ---------------------------------------------------------------------------
+# engine spans on the profiler's clock (Obs(jax_annotations=True))
+# ---------------------------------------------------------------------------
+
+def _profiled_run(eng, reqs, logdir):
+    """Step ``eng`` through ``reqs`` under ``jax.profiler.trace``; returns
+    the host events of the trace and, per step, the engine's own tallies:
+    (prefilled tokens, emitted tokens, requests given their first token)."""
+    import glob
+    from jax.profiler import ProfileData
+    for r in reqs:
+        eng.submit(r)
+    done = []
+    with jax.profiler.trace(str(logdir)):
+        while eng.scheduler.has_work:
+            pre, emi = eng.prefilled_tokens, eng.emitted_tokens
+            fresh = sum(1 for r in reqs if not r.out_tokens)
+            eng.step()
+            done.append((eng.prefilled_tokens - pre,
+                         eng.emitted_tokens - emi,
+                         fresh - sum(1 for r in reqs if not r.out_tokens)))
+    path, = glob.glob(str(logdir / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    events = [ev for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events]
+    return events, done
+
+
+def test_engine_step_spans_carry_what_the_step_did(qwen, tmp_path):
+    """Each ``engine.step`` span's counts equal the engine's own tallies
+    of that step: ``prefill_tokens`` the prefilled tokens, and
+    ``decode_slots`` the emitted tokens less the first tokens that
+    prefill gave; one device read per step still, annotations on."""
+    m, params = qwen
+    eng = _mk_engine(m, params, obs=Obs(jax_annotations=True))
+    reqs = [Request(tokens=list(range(3, 3 + n)), max_new_tokens=k)
+            for n, k in ((9, 5), (3, 7), (6, 3))]
+    events, done = _profiled_run(eng, reqs, tmp_path)
+    steps = sorted((ev for ev in events if ev.name == "engine.step"),
+                   key=lambda ev: ev.start_ns)
+    assert len(steps) == len(done) == eng.step_count
+    for i, (ev, (prefilled, emitted, first)) in enumerate(zip(steps, done)):
+        args = dict(ev.stats)
+        assert args["step"] == i
+        assert args["prefill_tokens"] == prefilled
+        assert args["decode_slots"] == emitted - first
+        assert args["live_pages"] >= 0
+    assert sum(p for p, _, _ in done) == eng.prefilled_tokens
+    assert sum(dict(ev.stats)["decode_slots"] for ev in steps) + len(reqs) \
+        == eng.emitted_tokens
+    # one read per decode step and one per finished prompt, as unannotated
+    decode_steps = sum(1 for ev in steps if dict(ev.stats)["decode_slots"])
+    assert eng.device_reads == decode_steps + len(reqs)
+    # the phases carry their own counts, under the engine's prefix
+    chunks = [dict(ev.stats) for ev in events
+              if ev.name == "engine.prefill_chunk"]
+    assert sum(c["tokens"] for c in chunks) == eng.prefilled_tokens
+    assert {c["req"] for c in chunks} == {r._seq for r in reqs}
+    decodes = [dict(ev.stats) for ev in events if ev.name == "engine.decode"]
+    assert sum(d["slots"] for d in decodes) == sum(
+        dict(ev.stats)["decode_slots"] for ev in steps)
+    assert all(d["positions"] >= d["slots"] for d in decodes)
+    names = {ev.name for ev in events}
+    assert {"engine.admit", "engine.upload", "engine.sample",
+            "engine.device_read"} <= names
+    assert not any(n in names for n in ("step", "decode", "upload"))
+    hists = eng.obs.metrics.snapshot()["histograms"]
+    assert "engine.phase.decode_s" in hists
+    assert not any(n in hists for n in ("engine.phase.step_s",
+                                        "engine.phase.upload_s"))
+
+
+def test_step_programs_are_named_in_the_profile(qwen, tmp_path):
+    """The host dispatches read ``PjitFunction(<program>)`` under the
+    programs' own names; none of the engine's programs is a lambda. One
+    slot: one device read per emitted token, annotations on."""
+    m, params = qwen
+    eng = _mk_engine(m, params, obs=Obs(jax_annotations=True))
+    events, _ = _profiled_run(
+        eng, [Request(tokens=[3, 4, 5], max_new_tokens=6)], tmp_path)
+    assert eng.device_reads == eng.emitted_tokens == 6
+    names = {ev.name for ev in events}
+    for prog in ("prefill_chunk", "decode_step", "sample_tokens"):
+        assert f"PjitFunction({prog})" in names, prog
+    assert "PjitFunction(<lambda>)" not in names
+    progs = {name: fn.__wrapped__.__name__
+             for name, fn in eng.jit_entry_points().items()}
+    assert progs == {"prefill": "prefill_chunk", "decode": "decode_step",
+                     "verify": "verify_step", "sample": "sample_tokens"}
+
+
+def test_annotations_off_phase_is_the_shared_null_context():
+    """Without annotations an inactive bundle hands back the shared null
+    context from ``phase`` (no allocation), and ``span`` does whatever
+    the timing; with them both are profiler spans that take arguments."""
+    assert Obs(timing=False).phase("decode") is NULL_CTX
+    assert Obs().span("step") is NULL_CTX
+    assert Obs.disabled().span("upload") is NULL_CTX
+    on = Obs(timing=False, jax_annotations=True)
+    for span in (on.phase("decode"), on.span("step")):
+        assert span is not NULL_CTX
+        with span:
+            span.set_metadata(slots=2, positions=9)
+    assert on.metrics.snapshot()["histograms"] == {}   # timing stays off

@@ -112,3 +112,57 @@ def test_flash_decode_compiles(one_chip, pool):
                    s((SLOTS, PAGES_PER_SLOT), jnp.int32),
                    s((SLOTS,), jnp.int32), cb)
     assert "tpu_custom_call" in hlo
+
+
+def test_decode_step_names_its_program_and_flash_kernel(one_chip,
+                                                        monkeypatch):
+    """The engine's decode step at Qwen1.5-4B's widths (8 slots of 1024,
+    pages of 16) compiles as ``jit_decode_step`` and holds one flash
+    split call, named ``flash_splits``, in the body of the loop over the
+    40 layers; the benchmark's matcher claims that call and no other
+    instruction (not the ops that take its results)."""
+    import re
+    from pathlib import Path
+    from repro.configs import get_config
+    from repro.core.lut import DENSE
+    from repro.models.model import Model
+    from repro.serve.engine import step_programs
+    monkeypatch.syspath_prepend(
+        str(Path(__file__).resolve().parents[1] / "bench"))
+    from work import flash_decode
+    # the kernel branches are chosen by the backend JAX reports
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("qwen1.5-4b")
+    model = Model(cfg)
+    qc = DENSE.replace(flash="pallas")
+    s = _spec(one_chip)
+    on_chip = lambda tree: jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), qc)))
+    kv = on_chip(jax.eval_shape(
+        lambda: model.init_paged_cache(SLOTS, 1024, PAGE, 512)))
+    decode_step = step_programs(model, qc)[1]
+    hlo = jax.jit(decode_step, donate_argnums=(2,)).lower(
+        params, s((SLOTS, 1), jnp.int32), kv,
+        s((SLOTS, PAGES_PER_SLOT), jnp.int32),
+        s((SLOTS,), jnp.int32)).compile().as_text()
+    assert hlo.startswith("HloModule jit_decode_step,")
+    comps, comp = {}, None
+    for line in hlo.splitlines():
+        if line and not line.startswith((" ", "}")):
+            comp = line.split(" ", 1)[0]
+            comps[comp] = []
+        elif comp is not None:
+            comps[comp].append(line.strip())
+    claimed = [(c, ins) for c, body in comps.items() for ins in body
+               if ins.startswith(("%", "ROOT %"))
+               and flash_decode.matches(ins)]
+    assert len(claimed) == 1, [ins[:80] for _, ins in claimed]
+    body, ins = claimed[0]
+    assert ins.startswith("%flash_splits.")
+    assert "flash_decode" not in ins
+    loops = [i for b in comps.values() for i in b
+             if f"body={body}" in i and " while(" in i]
+    assert len(loops) == 1
+    cond = re.search(r"condition=(%[\w.-]+)", loops[0]).group(1)
+    assert any(f"constant({cfg.num_layers})" in i for i in comps[cond])
