@@ -166,7 +166,7 @@ class Model:
           caller's write masking).
         return_slabs: single-token decode only — return the per-layer
           new-token KV slabs instead of writing them into ``cache`` at
-          ``q_offset`` (the paged-cache caller scatters them itself; this
+          ``q_offset`` (the paged-cache caller writes them itself; this
           is what makes per-slot write positions possible).
         multi_slab: treat a MULTI-token input like the decode slab path
           (speculative verify): the cache stays read-only, per-row
@@ -695,7 +695,7 @@ class Model:
             parallel ones shard subspaces and all-reduce partial sums).
 
         Returns (logits (1, V) at the last real token, updated kv).
-        Padded positions scatter to the trash page; the SSM path makes
+        Padded positions write to the trash page; the SSM path makes
         them recurrence-neutral via ``valid_len`` (see mamba2_block).
         """
         cfg = self.cfg
@@ -718,7 +718,7 @@ class Model:
             view = self._paged_view(kv, phys[None])
             x, _, _, new_view = self._run_blocks(
                 params, x, qc, q_offset=pos, prefix_len=0, cache=view)
-            # extract this chunk's fresh K/V rows and scatter them to pages
+            # extract this chunk's fresh K/V rows and write them to pages
             tok_pos = pos + jnp.arange(c)
             page, off = tok_pos // ps, tok_pos % ps
             live = jnp.arange(c) < valid_len
@@ -728,7 +728,7 @@ class Model:
                 rows = jax.lax.dynamic_slice_in_dim(
                     new_view[key][:, 0], pos, c, axis=1)          # (L,C,..)
                 rows = self._encode_rows(kv, key, rows)
-                new_kv[key] = kv[key].at[:, tgt, off].set(rows)
+                new_kv[key] = _write_rows(kv[key], rows, tgt, off)
         else:
             cache_view, write_back = self._slot_state_view(kv, slot, pos)
             x, _, _, new_state = self._run_blocks(
@@ -800,8 +800,8 @@ class Model:
             activations (see :meth:`prefill_paged`).
 
         Returns (logits (num_slots, V), updated kv). The new-token KV slab
-        is scattered at each decoding slot's own (page, offset); lanes
-        with positions < 0 scatter to the trash page (attention pool) /
+        is written at each decoding slot's own (page, offset); lanes
+        with positions < 0 write to the trash page (attention pool) /
         trash row (hybrid slot-dense cache). SSM states of inactive lanes
         do get garbage updates — harmless, because admission re-reads
         them as zeros (see :meth:`_slot_state_view`).
@@ -844,7 +844,7 @@ class Model:
             new_kv = dict(kv)
             for key in ("k", "v"):
                 slab = self._encode_rows(kv, key, slabs[key][:, :, 0])
-                new_kv[key] = kv[key].at[:, tgt, off].set(slab)
+                new_kv[key] = _write_rows(kv[key], slab, tgt, off)
         elif cfg.family == "ssm":
             x, _, _, upd = self._run_blocks(
                 params, x, qc, q_offset=positions, prefix_len=0, cache=kv)
@@ -889,7 +889,7 @@ class Model:
           positions: (num_slots,) committed length of each participating
             slot; -1 = lane not in this verify (free / mid-prefill).
           n_live: (num_slots,) live token columns per row (0 for -1
-            lanes). Columns >= n_live[b] scatter their KV to the trash
+            lanes). Columns >= n_live[b] write their KV to the trash
             page and their logits are garbage the caller must ignore.
 
         Returns (logits (num_slots, T, V), updated kv). Live columns'
@@ -928,7 +928,7 @@ class Model:
         x, _, _, slabs = self._run_blocks(
             params, x, qc, q_offset=positions, prefix_len=0,
             cache=view, return_slabs=True, multi_slab=True)
-        # scatter the T fresh rows per slot; dead columns -> trash page
+        # write the T fresh rows per slot; dead columns -> trash page
         tok_pos = pos_c[:, None] + jnp.arange(t_v)[None, :]       # (B, T)
         live = (positions >= 0)[:, None] \
             & (jnp.arange(t_v)[None, :] < n_live[:, None])
@@ -939,10 +939,31 @@ class Model:
         new_kv = dict(kv)
         for key in ("k", "v"):
             slab = self._encode_rows(kv, key, slabs[key])
-            new_kv[key] = kv[key].at[:, tgt, off].set(slab)
+            new_kv[key] = _write_rows(kv[key], slab, tgt, off)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = self._head(params, x)                            # (B, T, V)
         return logits, new_kv
+
+
+def _write_rows(pool: jax.Array, rows: jax.Array, tgt: jax.Array,
+                off: jax.Array) -> jax.Array:
+    """Write fresh K/V rows into a page pool in place.
+
+    pool: (L, P+1, page, KVH, W); rows: (L, *idx, KVH, W) with ``tgt``
+    (page ids) and ``off`` (offsets in the page) of shape ``idx``. Row i
+    goes to ``pool[:, tgt[i], off[i]]`` by one ``dynamic_update_slice``:
+    the TPU runs those in the pool's stored layout on the donated buffer,
+    where a scatter would relayout the whole pool into and out of its
+    own. Live targets never collide; dead rows all land on the trash
+    page, which nothing reads, so their order does not matter. tgt <= P
+    and off < page, so no start index is ever clamped."""
+    l, kvh, w = pool.shape[0], pool.shape[3], pool.shape[4]
+    rows = rows.reshape(l, -1, 1, 1, kvh, w).astype(pool.dtype)
+    tgt, off = tgt.reshape(-1), off.reshape(-1)
+    for i in range(rows.shape[1]):
+        pool = jax.lax.dynamic_update_slice(
+            pool, rows[:, i], (0, tgt[i], off[i], 0, 0))
+    return pool
 
 
 def _merge_live_states(old, new, live: jax.Array):

@@ -374,6 +374,36 @@ def _paged_parity(name, qc, params_fn):
                                    rtol=5e-3, atol=5e-3)
 
 
+@pytest.mark.parametrize("idx", [(5,), (3, 4), (12,)],
+                         ids=["decode", "verify", "chunk"])
+@pytest.mark.parametrize("pool", ["fp", "vq"])
+def test_write_rows_matches_scatter_off_the_trash_page(pool, idx):
+    """The in-place row write gives the pool a scatter gives, on every
+    page but the trash page: live rows at distinct (page, offset)
+    targets, dead rows all sent to the trash page, some to one offset."""
+    from repro.models.model import _write_rows
+    rng = np.random.default_rng(sum(idx) + len(pool))
+    l, p, ps, kvh, w = 3, 6, 4, 2, 8          # trash page id p
+    dtype, lo, hi = ((jnp.bfloat16, -4, 4) if pool == "fp"
+                     else (jnp.uint8, 0, 256))
+    base = jnp.asarray(rng.uniform(lo, hi, (l, p + 1, ps, kvh, w)), dtype)
+    rows = jnp.asarray(rng.uniform(lo, hi, (l, *idx, kvh, w)), dtype)
+    n = int(np.prod(idx))
+    cells = rng.choice(p * ps, n, replace=False)
+    live = rng.random(n) < 0.6
+    live[0], live[-1] = True, False
+    tgt = np.where(live, cells // ps, p)
+    off = np.where(live, cells % ps, rng.integers(0, 2, n))
+    tgt, off = jnp.asarray(tgt.reshape(idx)), jnp.asarray(off.reshape(idx))
+    got = jax.jit(_write_rows)(base, rows, tgt, off)
+    want = base.at[:, tgt, off].set(rows)
+    assert got.dtype == base.dtype and got.shape == base.shape
+    np.testing.assert_array_equal(np.asarray(got[:, :p], np.float32),
+                                  np.asarray(want[:, :p], np.float32))
+    assert not np.array_equal(np.asarray(got[:, :p], np.float32),
+                              np.asarray(base[:, :p], np.float32))
+
+
 def test_paged_parity_dense_attention():
     _paged_parity("qwen1.5-4b", DENSE, lambda m: m.init(KEY, DENSE))
 

@@ -114,6 +114,51 @@ def test_flash_decode_compiles(one_chip, pool):
     assert "tpu_custom_call" in hlo
 
 
+def _step_hlo(one_chip, program):
+    """``program`` ("decode_step" or "prefill_chunk") of the engine at
+    Qwen1.5-4B's widths (8 slots of 1024, pages of 16, a pool of 512
+    pages, chunks of 32), compiled with the pool donated as the engine
+    does. Returns (HLO text, the pool's HLO shape)."""
+    from repro.configs import get_config
+    from repro.core.lut import DENSE
+    from repro.models.model import Model
+    from repro.serve.engine import step_programs
+    cfg = get_config("qwen1.5-4b")
+    model = Model(cfg)
+    qc = DENSE.replace(flash="pallas")
+    s = _spec(one_chip)
+    on_chip = lambda tree: jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), qc)))
+    kv = on_chip(jax.eval_shape(
+        lambda: model.init_paged_cache(SLOTS, 1024, PAGE, 512)))
+    prefill_chunk, decode_step, _ = step_programs(model, qc)
+    table = s((SLOTS, PAGES_PER_SLOT), jnp.int32)
+    if program == "decode_step":
+        fn, args = decode_step, (s((SLOTS, 1), jnp.int32), kv, table,
+                                 s((SLOTS,), jnp.int32))
+    else:
+        scalar = s((), jnp.int32)
+        fn, args = prefill_chunk, (s((1, 32), jnp.int32), kv, table,
+                                   scalar, scalar, scalar)
+    hlo = jax.jit(fn, donate_argnums=(2,)).lower(
+        params, *args).compile().as_text()
+    assert kv["k"].dtype == jnp.bfloat16
+    return hlo, "bf16[" + ",".join(map(str, kv["k"].shape)) + "]"
+
+
+def _computations(hlo):
+    """{computation name: its instruction lines} of an HLO text."""
+    comps, comp = {}, None
+    for line in hlo.splitlines():
+        if line and not line.startswith((" ", "}")):
+            comp = line.split(" ", 1)[0]
+            comps[comp] = []
+        elif comp is not None:
+            comps[comp].append(line.strip())
+    return comps
+
+
 def test_decode_step_names_its_program_and_flash_kernel(one_chip,
                                                         monkeypatch):
     """The engine's decode step at Qwen1.5-4B's widths (8 slots of 1024,
@@ -124,36 +169,14 @@ def test_decode_step_names_its_program_and_flash_kernel(one_chip,
     import re
     from pathlib import Path
     from repro.configs import get_config
-    from repro.core.lut import DENSE
-    from repro.models.model import Model
-    from repro.serve.engine import step_programs
     monkeypatch.syspath_prepend(
         str(Path(__file__).resolve().parents[1] / "bench"))
     from work import flash_decode
     # the kernel branches are chosen by the backend JAX reports
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = get_config("qwen1.5-4b")
-    model = Model(cfg)
-    qc = DENSE.replace(flash="pallas")
-    s = _spec(one_chip)
-    on_chip = lambda tree: jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
-    params = on_chip(jax.eval_shape(
-        lambda: model.init(jax.random.PRNGKey(0), qc)))
-    kv = on_chip(jax.eval_shape(
-        lambda: model.init_paged_cache(SLOTS, 1024, PAGE, 512)))
-    decode_step = step_programs(model, qc)[1]
-    hlo = jax.jit(decode_step, donate_argnums=(2,)).lower(
-        params, s((SLOTS, 1), jnp.int32), kv,
-        s((SLOTS, PAGES_PER_SLOT), jnp.int32),
-        s((SLOTS,), jnp.int32)).compile().as_text()
+    hlo, _ = _step_hlo(one_chip, "decode_step")
     assert hlo.startswith("HloModule jit_decode_step,")
-    comps, comp = {}, None
-    for line in hlo.splitlines():
-        if line and not line.startswith((" ", "}")):
-            comp = line.split(" ", 1)[0]
-            comps[comp] = []
-        elif comp is not None:
-            comps[comp].append(line.strip())
+    comps = _computations(hlo)
     claimed = [(c, ins) for c, body in comps.items() for ins in body
                if ins.startswith(("%", "ROOT %"))
                and flash_decode.matches(ins)]
@@ -165,4 +188,34 @@ def test_decode_step_names_its_program_and_flash_kernel(one_chip,
              if f"body={body}" in i and " while(" in i]
     assert len(loops) == 1
     cond = re.search(r"condition=(%[\w.-]+)", loops[0]).group(1)
-    assert any(f"constant({cfg.num_layers})" in i for i in comps[cond])
+    num_layers = get_config("qwen1.5-4b").num_layers
+    assert any(f"constant({num_layers})" in i for i in comps[cond])
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_step_writes_kv_rows_in_place(one_chip, monkeypatch, program):
+    """The step writes its fresh K/V rows into the donated pool where it
+    lies: no instruction whose result is the whole pool is a copy (a
+    relayout into and out of a scatter's layout costs two passes over
+    1.68 GB a pool on every step) or a scatter, and the compiled program
+    hands both pool buffers back as its outputs."""
+    import re
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo, pool = _step_hlo(one_chip, program)
+    assert hlo.startswith(f"HloModule jit_{program},")
+    made = re.compile(r"(?:ROOT )?%[\w.-]+ = " + re.escape(pool)
+                      + r"\{[^}]*\} ([\w-]+)\(")
+    comps = _computations(hlo)
+    ops = [m.group(1) for body in comps.values()
+           for m in map(made.match, body) if m]
+    assert "dynamic-update-slice" in ops
+    relayouts = [op for op in ops if op.startswith("copy") or op == "scatter"]
+    assert not relayouts, relayouts
+    pool_params = {int(m.group(1)) for ins in comps["ENTRY"]
+                   for m in [re.search(re.escape(pool)
+                                       + r"\{[^}]*\} parameter\((\d+)\)",
+                                       ins)] if m}
+    assert len(pool_params) == 2
+    header = hlo.split("entry_computation_layout=", 1)[0]
+    aliased = {int(n) for n in re.findall(r"\{\d+\}: \((\d+), ", header)}
+    assert pool_params <= aliased, (pool_params, header)
