@@ -8,7 +8,7 @@ def config() -> ModelConfig:
         name="dbrx-132b", family="moe",
         num_layers=40, d_model=6144, num_heads=48, num_kv_heads=8,
         d_ff=10752, vocab_size=100352,
-        num_experts=16, top_k=4, capacity_factor=1.25,
+        num_experts=16, top_k=4,
         dtype="bfloat16", attn_impl="chunked", tie_embeddings=False)
 
 
@@ -17,5 +17,5 @@ def smoke_config() -> ModelConfig:
         name="dbrx-smoke", family="moe",
         num_layers=2, d_model=64, num_heads=4, num_kv_heads=2,
         d_ff=96, vocab_size=256,
-        num_experts=4, top_k=2, capacity_factor=2.0,
+        num_experts=4, top_k=2,
         dtype="float32", tie_embeddings=False)

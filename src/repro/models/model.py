@@ -30,7 +30,8 @@ from repro.kernels.flash_decode import resolve_flash_impl
 from .config import ModelConfig
 from .layers import (attention, init_attention, init_mlp, mlp, rms_norm)
 from .mamba2 import init_mamba2, mamba2_block, mamba2_decode
-from .moe import init_moe, moe_ffn
+from .mla import init_mla, mla_attention
+from .moe import EXPERT_KEYS, dense_experts, init_moe, moe_ffn
 
 Params = Dict[str, Any]
 
@@ -48,17 +49,30 @@ class Model:
     def dtype(self):
         return jnp.dtype(self.cfg.dtype)
 
-    def _init_block(self, key, qc: QuantConfig):
+    def _init_block(self, key, qc: QuantConfig, ffn: str = "mlp"):
         cfg, dtype = self.cfg, self.dtype
         if cfg.family in ("ssm", "hybrid"):
             return init_mamba2(key, cfg, qc, dtype)
         ka, kf = jax.random.split(key)
-        block = {"attn": init_attention(ka, cfg, qc, dtype)}
-        if cfg.family == "moe":
+        init_attn = init_mla if cfg.mla else init_attention
+        block = {"attn": init_attn(ka, cfg, qc, dtype)}
+        if ffn == "moe":
             block["moe"] = init_moe(kf, cfg, qc, dtype)
         else:
             block["mlp"] = init_mlp(kf, cfg.d_model, cfg.d_ff, cfg, qc, dtype)
         return block
+
+    def segments(self):
+        """The layer stack as runs of one block kind: (params key, first
+        layer, layers, FFN kind). Layers ``i < cfg.first_k_dense`` of an
+        MoE model keep a dense MLP (``dense_blocks``); every other layer
+        of it holds the experts (``blocks``)."""
+        cfg = self.cfg
+        if not cfg.num_experts:
+            return [("blocks", 0, cfg.num_layers, "mlp")]
+        k = cfg.first_k_dense
+        dense = [("dense_blocks", 0, k, "mlp")] if k else []
+        return dense + [("blocks", k, cfg.num_layers - k, "moe")]
 
     def init(self, key: jax.Array, qc: QuantConfig = DENSE) -> Params:
         """Random params from ``key``. Jitted, so each stacked weight is
@@ -74,8 +88,10 @@ class Model:
             params["embed"] = (0.02 * jax.random.normal(
                 ke, (cfg.vocab_size, cfg.d_model))).astype(dtype)
         layer_keys = jax.random.split(kb, cfg.num_layers)
-        params["blocks"] = jax.vmap(
-            lambda k: self._init_block(k, qc))(layer_keys)
+        for name, lo, n, ffn in self.segments():
+            params[name] = jax.vmap(
+                lambda k, ffn=ffn: self._init_block(k, qc, ffn))(
+                    layer_keys[lo:lo + n])
         if cfg.family == "hybrid":
             ka, km = jax.random.split(ks)
             params["shared_attn"] = {
@@ -155,8 +171,10 @@ class Model:
                     cache: Optional[Params] = None,
                     kv_start=0, valid_len=None, return_slabs: bool = False,
                     multi_slab: bool = False,
-                    paged_phys=None, flash_impl: str = "ref"):
-        """Scan over the layer stack. Returns (x, recon, moe_aux, new_cache).
+                    paged_phys=None, flash_impl: str = "ref",
+                    live=None, return_load: bool = False):
+        """Scan over the layer stack. Returns (x, recon, moe_aux, new_cache),
+        and with ``return_load`` also the MoE layers' load (see below).
 
         q_offset: scalar, or (B,) per-row decode positions (paged serving).
         kv_start: scalar or (B,) — mask cache rows < kv_start (left-padded
@@ -178,6 +196,11 @@ class Model:
           and each layer's attention runs the flash-decode kernel
           through the page table instead of over a gathered view (see
           ``decode_paged`` and kernels/flash_decode.py).
+        live / return_load: MoE models — ``live`` (B*S,) marks the tokens
+          whose routed (token, choice) pairs are counted, and the fifth
+          return value is the count per MoE layer and held expert
+          ``(MoE layers, held)`` int32 (None for a model without
+          experts). Attention families, flat scan only.
         """
         cfg = self.cfg
         windows = self._windows()
@@ -195,24 +218,35 @@ class Model:
             # over groups of `global_every` with the window STATIC per
             # sub-layer, enabling the block-local attention fast path.
             slab_mode = decode and cfg.head_layout != "hd"
+            attn_fn = mla_attention if cfg.mla else attention
 
-            def layer_fn(h, recon, aux, c_full, p_l, win, li):
+
+            def layer_fn(h, recon, aux, c_full, p_l, win, li, ffn="mlp",
+                         stack=None, local=None):
                 src = cache if slab_mode else c_full
                 c_l = None
-                if src is not None:
+                if slab_mode and cfg.mla and paged_phys is not None:
+                    # the latent pool is read in place at this layer's
+                    # index: no per-layer slice of it is ever made
+                    c_l = dict(src, layer=li)
+                elif src is not None:
                     c_l = jax.tree_util.tree_map(
                         lambda t: jax.lax.dynamic_index_in_dim(
                             t, li, 0, keepdims=False), src)
-                a, r1, new_c = attention(p_l["attn"], h, cfg, qc,
-                                         q_offset=q_offset, window=win,
-                                         prefix_len=prefix_len, cache=c_l,
-                                         decode_slab=slab_mode,
-                                         kv_start=kv_start,
-                                         paged_phys=paged_phys,
-                                         flash_impl=flash_impl)
+                a, r1, new_c = attn_fn(p_l["attn"], h, cfg, qc,
+                                       q_offset=q_offset, window=win,
+                                       prefix_len=prefix_len, cache=c_l,
+                                       decode_slab=slab_mode,
+                                       kv_start=kv_start,
+                                       paged_phys=paged_phys,
+                                       flash_impl=flash_impl)
                 h = h + a
-                if cfg.family == "moe":
-                    f, r2, a2 = moe_ffn(p_l["moe"], h, cfg, qc)
+                load = None
+                if ffn == "moe":
+                    pm = p_l["moe"] if stack is None else dict(
+                        p_l["moe"], **stack)
+                    f, r2, a2, load = moe_ffn(pm, h, cfg, qc, live=live,
+                                              layer=local)
                     aux = aux + a2
                 else:
                     f, r2 = mlp(p_l["mlp"], h, cfg, qc)
@@ -225,7 +259,7 @@ class Model:
                         lambda full, upd: jax.lax.dynamic_update_index_in_dim(
                             full, upd.astype(full.dtype), li, 0),
                         c_full, new_c)
-                return h, recon + r1 + r2, aux, c_full, slab
+                return h, recon + r1 + r2, aux, c_full, (slab, load)
 
             ge = cfg.global_every
             # grouping pays off where the static window enables the
@@ -257,7 +291,7 @@ class Model:
                             lambda t: t[j], g_params)
                         win = 0 if j == ge - 1 else cfg.sliding_window
                         li = gid * ge + j
-                        h, recon, aux, c_full, slab = layer_fn(
+                        h, recon, aux, c_full, (slab, _) = layer_fn(
                             h, recon, aux, c_full, p_l, win, li)
                         slabs.append(slab)
                     ys = (jax.tree_util.tree_map(
@@ -289,7 +323,7 @@ class Model:
                     p_l = jax.tree_util.tree_map(lambda t: t[j], tail_p)
                     win = 0 if cfg.layer_is_global(li) else \
                         cfg.sliding_window
-                    x, recon, aux, c_full, slab = layer_fn(
+                    x, recon, aux, c_full, (slab, _) = layer_fn(
                         x, recon, aux, c_full, p_l, win, jnp.int32(li))
                     if slab_mode:
                         slab_list.append(jax.tree_util.tree_map(
@@ -306,36 +340,57 @@ class Model:
                         for key in ("k", "v")}
                 return x, recon, aux, new_cache
 
-            def body(carry, xs):
-                if carry_cache:
-                    h, recon, aux, c_full = carry
-                else:
-                    h, recon, aux = carry
-                    c_full = None
-                p_l, win, li = xs
-                h, recon, aux, c_full, slab = layer_fn(
-                    h, recon, aux, c_full, p_l, win, li)
-                if carry_cache:
-                    return (h, recon, aux, c_full), slab
-                return (h, recon, aux), slab
+            def make_body(ffn, stack, lo):
+                def body(carry, xs):
+                    if carry_cache:
+                        h, recon, aux, c_full = carry
+                    else:
+                        h, recon, aux = carry
+                        c_full = None
+                    p_l, win, li = xs
+                    h, recon, aux, c_full, ys = layer_fn(
+                        h, recon, aux, c_full, p_l, win, li, ffn, stack,
+                        None if stack is None else li - lo)
+                    if carry_cache:
+                        return (h, recon, aux, c_full), ys
+                    return (h, recon, aux), ys
+                return jax.checkpoint(body) if cfg.remat else body
 
-            if cfg.remat:
-                body = jax.checkpoint(body)
             layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
-            xs = (params["blocks"], windows, layer_ids)
-            carry0 = (x, z0, z0, cache) if carry_cache else (x, z0, z0)
-            carry, slabs = jax.lax.scan(body, carry0, xs)
+            carry = (x, z0, z0, cache) if carry_cache else (x, z0, z0)
+            slab_runs, loads = [], []
+            for name, lo, n, ffn in self.segments():
+                seg, stack = params[name], None
+                if ffn == "moe" and dense_experts(seg["moe"], qc):
+                    # dense experts stay stacked: the kernel reads each
+                    # layer's where it lies (see moe_ffn)
+                    stack = {k: v for k, v in seg["moe"].items()
+                             if k in EXPERT_KEYS}
+                    seg = dict(seg, moe={k: v for k, v in seg["moe"].items()
+                                         if k not in EXPERT_KEYS})
+                xs = (seg, windows[lo:lo + n], layer_ids[lo:lo + n])
+                carry, (slabs, load) = jax.lax.scan(
+                    make_body(ffn, stack, lo), carry, xs)
+                slab_runs.append(slabs)
+                if load is not None:
+                    loads.append(load)
+            slabs = (slab_runs[0] if len(slab_runs) == 1 else
+                     jax.tree_util.tree_map(
+                         lambda *t: jnp.concatenate(t, 0), *slab_runs))
+            load = loads[0] if loads else None
             if carry_cache:
                 x, recon, aux, new_cache = carry
-                return x, recon, aux, new_cache
+                return self._with_load(return_load, load,
+                                       (x, recon, aux, new_cache))
             x, recon, aux = carry
             if slab_mode:
-                if return_slabs:          # (L, B, 1, KVH, HD) per key
-                    return x, recon, aux, slabs
+                if return_slabs:      # (L, B, 1, ...) per pool key
+                    return self._with_load(return_load, load,
+                                           (x, recon, aux, slabs))
                 new_cache = {
                     key: jax.lax.dynamic_update_slice_in_dim(
                         cache[key], slabs[key], q_offset, axis=2)
-                    for key in ("k", "v")}
+                    for key in slabs}
                 return x, recon, aux, new_cache
             return x, recon, aux, None
 
@@ -462,6 +517,10 @@ class Model:
         (x, recon, aux), new_cache = jax.lax.scan(body, (x, z0, z0), xs)
         return x, recon, aux, new_cache
 
+    @staticmethod
+    def _with_load(return_load: bool, load, out):
+        return out + (load,) if return_load else out
+
     # ------------------------------------------------------------------
     # train forward + loss
     # ------------------------------------------------------------------
@@ -511,6 +570,10 @@ class Model:
         l, b, t = cfg.num_layers, batch_size, max_seq
         kvh, hd = cfg.num_kv_heads, cfg.head_dim
         pos = jnp.zeros((), jnp.int32)
+        if cfg.mla:
+            raise NotImplementedError(
+                "latent attention serves from the paged latent pool "
+                "(init_paged_cache)")
         if cfg.family in ATTN_FAMILIES:
             return {"layers": {
                 "k": jnp.zeros((l, b, t, kvh, hd), dtype),
@@ -584,7 +647,10 @@ class Model:
 
         Attention families return a page pool ``{"k": (L, num_pages+1,
         page_size, KVH, HD), "v": ...}`` — one extra *trash* page (the
-        last id) absorbs writes from padded / inactive positions. SSM
+        last id) absorbs writes from padded / inactive positions. Latent
+        attention (``cfg.mla``) keeps one row a position instead, the
+        latent and its RoPE key: ``{"latent": (L, num_pages+1, page_size,
+        cfg.latent_row)}``, under the same page table. SSM
         state is O(1) per sequence, so it stays slot-indexed
         (``(L, num_slots, ...)``) and is recycled on eviction; the hybrid
         family keeps its few shared-attention invocations slot-dense.
@@ -599,10 +665,19 @@ class Model:
         cfg = self.cfg
         dtype = dtype or self.dtype
         l, kvh, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
-        if codebook is not None and cfg.family not in ATTN_FAMILIES:
+        if codebook is not None and (cfg.family not in ATTN_FAMILIES
+                                     or cfg.mla):
             raise NotImplementedError(
-                "KV quantization applies to paged attention pools only; "
-                f"the {cfg.family!r} family has recurrent state")
+                "KV quantization applies to per-head K/V page pools only")
+        if cfg.mla:
+            # rows are written in the activations' dtype. Off the TPU a
+            # bf16 pool is kept in float32, which holds them exactly: the
+            # CPU backend converts a whole bf16 array for each in-place
+            # row write, which at 128 slots of 1024 costs seconds a step.
+            if jax.default_backend() != "tpu" and dtype == jnp.bfloat16:
+                dtype = jnp.float32
+            return {"latent": jnp.zeros(
+                (l, num_pages + 1, page_size, cfg.latent_row), dtype)}
         if cfg.family in ATTN_FAMILIES:
             if codebook is not None:
                 if (codebook.num_layers, codebook.head_dim) != (l, hd):
@@ -642,7 +717,8 @@ class Model:
                          "v": jnp.zeros(shape, dtype)}}
 
     def _paged_view(self, kv: Params, phys: jax.Array):
-        """Gather pages into a dense (L, B, NP*page, KVH, HD) KV view.
+        """Gather pages into a dense (L, B, NP*page, ...) view of each
+        pool leaf (``k``/``v``: (.., KVH, HD); ``latent``: (.., W)).
 
         phys: (B, NP) physical page ids (already trash-redirected).
         A quantized pool (``"codebook"`` in kv) is gathered as uint8
@@ -650,16 +726,14 @@ class Model:
         plain ``{"k", "v"}`` fp, so every gathered-view consumer
         (prefill, verify, the legacy gather decode path) reuses the
         dense attention math unchanged."""
-        l = kv["k"].shape[0]
-        ps = kv["k"].shape[2]
         b, np_ = phys.shape
-        kvh, w = kv["k"].shape[3], kv["k"].shape[4]
 
         def view(pages):
-            return pages[:, phys].reshape(l, b, np_ * ps, kvh, w)
+            l, _, ps, *row = pages.shape
+            return pages[:, phys].reshape(l, b, np_ * ps, *row)
         cb = kv.get(CODEBOOK_KEY)
         if cb is None:
-            return {"k": view(kv["k"]), "v": view(kv["v"])}
+            return {key: view(kv[key]) for key in _pool_keys(kv)}
         dt = self.dtype
         return {"k": kv_decode_stacked(view(kv["k"]), cb["zk"], cb["sk"], dt),
                 "v": kv_decode_stacked(view(kv["v"]), cb["zv"], cb["sv"], dt)}
@@ -710,12 +784,12 @@ class Model:
             x = jax.lax.with_sharding_constraint(x, act_sharding)
         c = tokens.shape[1]
         if cfg.family in ATTN_FAMILIES:
-            trash = kv["k"].shape[1] - 1
-            ps = kv["k"].shape[2]
+            trash, ps = _trash_and_page(kv)
             row = jax.lax.dynamic_index_in_dim(page_table, slot, 0,
                                                keepdims=False)    # (NP,)
             phys = jnp.where(row >= 0, row, trash)
-            view = self._paged_view(kv, phys[None])
+            view = (_latent_view(kv, phys) if cfg.mla
+                    else self._paged_view(kv, phys[None]))
             x, _, _, new_view = self._run_blocks(
                 params, x, qc, q_offset=pos, prefix_len=0, cache=view)
             # extract this chunk's fresh K/V rows and write them to pages
@@ -724,7 +798,7 @@ class Model:
             live = jnp.arange(c) < valid_len
             tgt = jnp.where(live, phys[page], trash)              # (C,)
             new_kv = dict(kv)       # codebook (if any) passes through
-            for key in ("k", "v"):
+            for key in _pool_keys(kv):
                 rows = jax.lax.dynamic_slice_in_dim(
                     new_view[key][:, 0], pos, c, axis=1)          # (L,C,..)
                 rows = self._encode_rows(kv, key, rows)
@@ -784,7 +858,8 @@ class Model:
 
     def decode_paged(self, params: Params, tokens: jax.Array, kv: Params,
                      page_table: jax.Array, positions: jax.Array,
-                     qc: QuantConfig = DENSE, act_sharding=None):
+                     qc: QuantConfig = DENSE, act_sharding=None,
+                     with_load: bool = False):
         """One decode step over ALL slots at per-slot positions.
 
         Args:
@@ -798,9 +873,13 @@ class Model:
           page_table: (num_slots, pages_per_slot) int32, -1 = unallocated.
           act_sharding: optional sharding constraint for the embedded
             activations (see :meth:`prefill_paged`).
+          with_load: for a model with MoE layers, also return the routed
+            (token, choice) pairs of the decoding lanes per MoE layer and
+            held expert, ``(MoE layers, held)`` int32.
 
-        Returns (logits (num_slots, V), updated kv). The new-token KV slab
-        is written at each decoding slot's own (page, offset); lanes
+        Returns (logits (num_slots, V), updated kv[, load]). The
+        new-token KV slab is written at each decoding slot's own (page,
+        offset); lanes
         with positions < 0 write to the trash page (attention pool) /
         trash row (hybrid slot-dense cache). SSM states of inactive lanes
         do get garbage updates — harmless, because admission re-reads
@@ -819,30 +898,37 @@ class Model:
             x = jax.lax.with_sharding_constraint(x, act_sharding)
         live = positions >= 0                 # decoding lanes only
         pos_c = jnp.maximum(positions, 0)
+        load = None
         if cfg.family in ATTN_FAMILIES:
-            trash = kv["k"].shape[1] - 1
-            ps = kv["k"].shape[2]
+            trash, ps = _trash_and_page(kv)
             phys = jnp.where(page_table >= 0, page_table, trash)  # (B, NP)
             # dispatch knob (mirrors QuantConfig.fuse): "gather" is the
             # legacy dense-view path; "pallas"/"ref" run flash decode
-            # straight off the page pool (kernels/flash_decode.py).
+            # straight off the page pool (kernels/flash_decode.py). The
+            # latent pool is always read in place (kernels/mla_decode.py:
+            # the kernel under "pallas", a gathered view otherwise).
             flash = resolve_flash_impl(qc.flash)
-            if flash == "gather":
+            counted = with_load and cfg.num_experts > 0
+            extra = dict(live=live, return_load=True) if counted else {}
+            if flash == "gather" and not cfg.mla:
                 view = self._paged_view(kv, phys)
-                x, _, _, slabs = self._run_blocks(
+                out = self._run_blocks(
                     params, x, qc, q_offset=positions, prefix_len=0,
-                    cache=view, return_slabs=True)
+                    cache=view, return_slabs=True, **extra)
             else:
-                x, _, _, slabs = self._run_blocks(
+                out = self._run_blocks(
                     params, x, qc, q_offset=positions, prefix_len=0,
                     cache=kv, return_slabs=True,
-                    paged_phys=phys, flash_impl=flash)
+                    paged_phys=phys, flash_impl=flash, **extra)
+            x, slabs = out[0], out[3]
+            if counted:
+                load = out[4]
             page, off = pos_c // ps, pos_c % ps
             # non-decoding lanes MUST NOT write through their page table:
             # a mid-prefill slot's pages hold real prompt KV.
             tgt = jnp.where(live, phys[jnp.arange(b), page], trash)
             new_kv = dict(kv)
-            for key in ("k", "v"):
+            for key in _pool_keys(kv):
                 slab = self._encode_rows(kv, key, slabs[key][:, :, 0])
                 new_kv[key] = _write_rows(kv[key], slab, tgt, off)
         elif cfg.family == "ssm":
@@ -866,6 +952,8 @@ class Model:
                       "attn": attn}
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = self._head(params, x)[:, 0]
+        if load is not None:
+            return logits, new_kv, load
         return logits, new_kv
 
     def verify_paged(self, params: Params, tokens: jax.Array, kv: Params,
@@ -909,6 +997,10 @@ class Model:
                 "verify_paged needs rewindable (paged) KV state; the "
                 f"{cfg.family!r} family's recurrent state cannot roll "
                 "back rejected draft tokens")
+        if cfg.mla:
+            raise NotImplementedError(
+                "verify_paged scores per-head K/V; the latent pool has "
+                "no multi-token verify")
         if cfg.head_layout == "hd":
             raise NotImplementedError("paged serving requires head_layout="
                                       "'heads'")
@@ -920,8 +1012,7 @@ class Model:
         if act_sharding is not None:
             x = jax.lax.with_sharding_constraint(x, act_sharding)
         pos_c = jnp.maximum(positions, 0)
-        trash = kv["k"].shape[1] - 1
-        ps = kv["k"].shape[2]
+        trash, ps = _trash_and_page(kv)
         max_seq = page_table.shape[1] * ps
         phys = jnp.where(page_table >= 0, page_table, trash)      # (B, NP)
         view = self._paged_view(kv, phys)
@@ -945,24 +1036,50 @@ class Model:
         return logits, new_kv
 
 
+def _latent_view(kv: Params, phys: jax.Array) -> Params:
+    """One slot's dense view ``{"latent": (L, 1, NP*page, W)}`` of a latent
+    pool, page by page with dynamic slices. (The TPU compiler splits a
+    gather of whole 640-wide pages into two lane slices of the whole pool
+    first: 4.5 GB of copies a prefill chunk at Moonlight's size.)"""
+    pool = kv["latent"]
+    pages = [jax.lax.dynamic_slice_in_dim(pool, phys[j], 1, axis=1)
+             for j in range(phys.shape[0])]
+    l, _, ps, w = pool.shape
+    return {"latent": jnp.concatenate(pages, axis=1).reshape(
+        l, 1, phys.shape[0] * ps, w)}
+
+
+def _pool_keys(kv: Params):
+    """The page-pool leaves of a paged attention cache (``k``/``v`` or
+    ``latent``): every leaf but a quantized pool's codebook."""
+    return [key for key in kv if key != CODEBOOK_KEY]
+
+
+def _trash_and_page(kv: Params):
+    """(trash page id, page size) of a paged attention cache."""
+    pool = kv[_pool_keys(kv)[0]]
+    return pool.shape[1] - 1, pool.shape[2]
+
+
 def _write_rows(pool: jax.Array, rows: jax.Array, tgt: jax.Array,
                 off: jax.Array) -> jax.Array:
     """Write fresh K/V rows into a page pool in place.
 
-    pool: (L, P+1, page, KVH, W); rows: (L, *idx, KVH, W) with ``tgt``
-    (page ids) and ``off`` (offsets in the page) of shape ``idx``. Row i
+    pool: (L, P+1, page, *row) (row: (KVH, W) of a K/V pool, (W,) of a
+    latent pool); rows: (L, *idx, *row) with ``tgt`` (page ids) and
+    ``off`` (offsets in the page) of shape ``idx``. Row i
     goes to ``pool[:, tgt[i], off[i]]`` by one ``dynamic_update_slice``:
     the TPU runs those in the pool's stored layout on the donated buffer,
     where a scatter would relayout the whole pool into and out of its
     own. Live targets never collide; dead rows all land on the trash
     page, which nothing reads, so their order does not matter. tgt <= P
     and off < page, so no start index is ever clamped."""
-    l, kvh, w = pool.shape[0], pool.shape[3], pool.shape[4]
-    rows = rows.reshape(l, -1, 1, 1, kvh, w).astype(pool.dtype)
+    l, row = pool.shape[0], pool.shape[3:]
+    rows = rows.reshape(l, -1, 1, 1, *row).astype(pool.dtype)
     tgt, off = tgt.reshape(-1), off.reshape(-1)
     for i in range(rows.shape[1]):
         pool = jax.lax.dynamic_update_slice(
-            pool, rows[:, i], (0, tgt[i], off[i], 0, 0))
+            pool, rows[:, i], (0, tgt[i], off[i]) + (0,) * len(row))
     return pool
 
 

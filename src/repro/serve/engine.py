@@ -185,7 +185,10 @@ def step_programs(model, qc: QuantConfig, act_sharding=None):
     Jitted under these names, each shows in a profile as
     ``PjitFunction(<name>)`` on the host and as ``jit_<name>(...)`` on
     the device's ``XLA Modules`` line. ``act_sharding`` is the sharded
-    engine's replicated activation placement (``Model.prefill_paged``)."""
+    engine's replicated activation placement (``Model.prefill_paged``).
+    For a model with MoE layers the decode step also returns the routed
+    (token, choice) pairs per MoE layer and held expert
+    (``Model.decode_paged(with_load=True)``)."""
 
     def prefill_chunk(p, t, kv, pt, slot, pos, valid):
         return model.prefill_paged(p, t, kv, pt, slot, pos, valid, qc,
@@ -193,13 +196,22 @@ def step_programs(model, qc: QuantConfig, act_sharding=None):
 
     def decode_step(p, t, kv, pt, positions):
         return model.decode_paged(p, t, kv, pt, positions, qc,
-                                  act_sharding=act_sharding)
+                                  act_sharding=act_sharding, with_load=True)
 
     def verify_step(p, t, kv, pt, pos, nl):
         return _with_argmax(*model.verify_paged(
             p, t, kv, pt, pos, nl, qc, act_sharding=act_sharding))
 
     return prefill_chunk, decode_step, verify_step
+
+
+def moe_stats(load) -> Dict[str, int]:
+    """``engine.step`` arguments from a decode step's routing counts
+    (MoE layers, held experts): the routed (token, choice) pairs in all,
+    the largest count of one expert in one layer, and the (layer, expert)
+    pairs that saw a token. ``load`` is the host copy the step read back."""
+    return {"moe_pairs": int(load.sum()), "moe_max_load": int(load.max()),
+            "moe_experts_hit": int((load > 0).sum())}
 
 
 def _sample_tokens(key: jax.Array, logits: jax.Array,
@@ -414,6 +426,7 @@ class Engine:
         # the serving loop goes through _device_read, which bumps this.
         self._c_device_reads = met.counter("engine.device_reads",
                                            unit="reads")
+        self._moe_stats: Dict[str, int] = {}
 
         # Speculative decoding (docs/speculative.md): draft cheap, verify
         # with the target in one multi-token call, roll back rejections.
@@ -494,7 +507,8 @@ class Engine:
         self._jit_decode = jax.jit(
             decode,
             in_shardings=(pshard, repl, cshard, repl, repl),
-            out_shardings=(repl, cshard),
+            out_shardings=(repl, cshard) + ((repl,) if cfg.num_experts
+                                            else ()),
             donate_argnums=(2,))
         self._param_sharding = pshard
         self._cache_sharding = cshard
@@ -773,7 +787,9 @@ class Engine:
 
         With ``Obs(jax_annotations=True)`` the step is the profiler span
         ``engine.step``, carrying ``step``, ``decode_slots``,
-        ``prefill_tokens`` and ``live_pages`` (docs/observability.md).
+        ``prefill_tokens`` and ``live_pages``, and for a model with MoE
+        layers ``moe_pairs``, ``moe_max_load`` and ``moe_experts_hit`` of
+        its decode (docs/observability.md).
         """
         obs = self.obs
         with obs.span("step") as span:
@@ -801,7 +817,8 @@ class Engine:
             if obs.jax_annotations:
                 span.set_metadata(step=self.step_count, decode_slots=decode_slots,
                           prefill_tokens=prefill_tokens,
-                          live_pages=self.kv.live_pages)
+                          live_pages=self.kv.live_pages,
+                          **(self._moe_stats if decode_slots else {}))
             self.step_count += 1
         return progressed
 
@@ -964,12 +981,18 @@ class Engine:
                 if obs.jax_annotations:
                     span.set_metadata(slots=len(dslots),
                               positions=sum(s.pos for s in dslots))
-                logits, self.kv.data = self._jit_decode(
+                logits, self.kv.data, *load = self._jit_decode(
                     self.params, toks_d, self.kv.data, table, pos_d)
             with obs.phase("sample"):
                 self.key, nxt_dev = self._jit_sample(self.key, logits,
                                                      temps)
-        nxt = self._device_read(nxt_dev)
+        # a MoE model's routing counts come back with the tokens, in the
+        # step's one read-back
+        if load:
+            nxt, load = self._device_read((nxt_dev, load[0]))
+            self._moe_stats = moe_stats(load)
+        else:
+            nxt = self._device_read(nxt_dev)
         for s in dslots:
             s.pos += 1
             self._record_token(s, int(nxt[s.idx]))

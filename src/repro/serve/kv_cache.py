@@ -42,6 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.kv_codebook import CODEBOOK_KEY
+
 
 class PagePoolExhausted(RuntimeError):
     """Raised when a page allocation cannot be satisfied.
@@ -582,7 +584,8 @@ def _copy_page(data: Dict[str, jax.Array], src, dst) -> Dict[str, jax.Array]:
     """Copy one physical page's K/V rows (CoW fork). ``src``/``dst`` are
     traced scalars, so every fork reuses one compiled executable.
 
-    Callers must pass ONLY the page-pool leaves (``{"k", "v"}``) — the
+    Callers must pass ONLY the page-pool leaves (``{"k", "v"}`` or
+    ``{"latent"}``) — the
     axis-1 copy is meaningless for anything else (a quantized cache's
     codebook tables, say), and would silently corrupt it."""
     return jax.tree_util.tree_map(
@@ -596,7 +599,8 @@ class PagedKVCache:
 
       * attention families: ``{"k": (L, P+1, page, KVH, HD), "v": ...}``
         where ``P`` is the physical pool size and the final page is the
-        trash page (see module docstring).
+        trash page (see module docstring); latent attention keeps one
+        ``{"latent": (L, P+1, page, W)}`` pool under the same table.
       * ssm: ``{"conv": (L, slots, K-1, C), "h": (L, slots, H, HP, N)}`` —
         slot-indexed recurrent state, recycled on eviction.
       * hybrid: ``{"mamba": {...}, "attn": {"k": (n_inv, slots, T, KVH,
@@ -648,11 +652,16 @@ class PagedKVCache:
         if not self.paged:
             return 0
         total = 0
-        for key in ("k", "v"):
-            t = self.data[key]          # (L, P+1, page, KVH, W)
-            l, _, _, kvh, w = t.shape
-            total += l * kvh * w * t.dtype.itemsize
+        for key in self._page_keys():
+            t = self.data[key]          # (L, P+1, page, *row)
+            total += (t.size // (t.shape[1] * t.shape[2])
+                      * t.dtype.itemsize)
         return total
+
+    def _page_keys(self):
+        """The pool leaves with a page axis (a quantized pool also holds
+        its codebook, which has none)."""
+        return [key for key in self.data if key != CODEBOOK_KEY]
 
     @property
     def page_bytes(self) -> int:
@@ -732,7 +741,7 @@ class PagedKVCache:
             src, dst = pair
             # page leaves only: a quantized cache also carries the
             # codebook pytree, which has no page axis to copy along
-            pages = {key: self.data[key] for key in ("k", "v")}
+            pages = {key: self.data[key] for key in self._page_keys()}
             copied = _copy_page(pages, jnp.int32(src), jnp.int32(dst))
             self.data = {**self.data, **copied}
             self.cow_forks += 1

@@ -63,7 +63,9 @@ def test_full_config_matches_assignment(name):
         "gemma3-4b": (34, 2560, 8, 4, 10240, 262144),
         "yi-9b": (48, 4096, 32, 4, 11008, 64000),
         "dbrx-132b": (40, 6144, 48, 8, 10752, 100352),
-        "deepseek-moe-16b": (28, 2048, 16, 16, 1408, 102400),
+        "deepseek-moe-16b": (28, 2048, 16, 16, 10944, 102400),
+        "moonlight-16b-a3b": (27, 2048, 16, 16, 11264, 163840),
+        "moonlight-16b-a3b-ep8": (27, 2048, 16, 16, 11264, 163840),
         "musicgen-large": (48, 2048, 32, 32, 8192, 2048),
         "paligemma-3b": (18, 2048, 8, 1, 16384, 257216),
     }[name]
@@ -76,6 +78,15 @@ def test_full_config_matches_assignment(name):
     if name == "deepseek-moe-16b":
         assert (cfg.num_experts, cfg.num_shared_experts, cfg.top_k) \
             == (64, 2, 6)
+        assert (cfg.moe_d_ff, cfg.first_k_dense) == (1408, 1)
+    if name.startswith("moonlight-16b-a3b"):
+        assert (cfg.num_experts, cfg.num_shared_experts, cfg.top_k,
+                cfg.moe_d_ff, cfg.first_k_dense) == (64, 2, 6, 1408, 1)
+        assert (cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                cfg.v_head_dim) == (512, 128, 64, 128)
+        assert (cfg.router_scoring, cfg.router_bias, cfg.routed_scale) \
+            == ("sigmoid", True, 2.446)
+        assert cfg.experts_held == (8 if name.endswith("-ep8") else 64)
     if name == "zamba2-1.2b":
         assert cfg.ssm_state == 64 and cfg.family == "hybrid"
     if name == "mamba2-2.7b":
@@ -149,6 +160,13 @@ def test_param_count_sanity():
     assert cfg.active_param_count() < 0.4 * cfg.param_count()  # top-4 of 16
     cfg = get_config("mamba2-2.7b")
     assert 2.0e9 < cfg.param_count() < 3.4e9
+    # Moonlight-16B-A3B: 15.96 B published (untied head), 3.36 B in one
+    # chip's share of an 8-way expert-parallel deployment
+    assert 15.9e9 < get_config("moonlight-16b-a3b").param_count() < 16.0e9
+    share = get_config("moonlight-16b-a3b-ep8")
+    assert 3.3e9 < share.param_count() < 3.4e9
+    assert share.active_param_count() \
+        == get_config("moonlight-16b-a3b").active_param_count()
 
 
 def test_gemma3_local_global_pattern():

@@ -219,3 +219,124 @@ def test_step_writes_kv_rows_in_place(one_chip, monkeypatch, program):
     header = hlo.split("entry_computation_layout=", 1)[0]
     aliased = {int(n) for n in re.findall(r"\{\d+\}: \((\d+), ", header)}
     assert pool_params <= aliased, (pool_params, header)
+
+
+# Moonlight-16B-A3B's widths: 16 heads of latent attention over a latent
+# row of 512 + 64 (640 with its tile padding), 8 held experts of 1408 over
+# d_model 2048, 128 slots of 1024 positions in pages of 16
+ML_SLOTS, ML_ROW, ML_HEADS, ML_RANK = 128, 640, 16, 512
+
+
+def test_mla_decode_kernel_compiles(one_chip):
+    from repro.kernels.mla_decode import mla_decode_paged
+    s = _spec(one_chip)
+    hlo = _compile(
+        lambda q, pool, new, phys, pos, layer: mla_decode_paged(
+            q, pool, new, phys, pos, ML_RANK, layer=layer, impl="pallas"),
+        s((ML_SLOTS, ML_HEADS, ML_ROW), jnp.float32),
+        s((2, ML_SLOTS * PAGES_PER_SLOT + 1, PAGE, ML_ROW), jnp.bfloat16),
+        s((ML_SLOTS, ML_ROW), jnp.bfloat16),
+        s((ML_SLOTS, PAGES_PER_SLOT), jnp.int32), s((ML_SLOTS,), jnp.int32),
+        s((), jnp.int32))
+    assert "tpu_custom_call" in hlo and "%mla_decode" in hlo
+
+
+@pytest.mark.parametrize("rows", [ML_SLOTS, 32])
+def test_moe_experts_kernel_compiles(one_chip, rows):
+    from repro.kernels.moe_experts import moe_experts_pallas
+    s = _spec(one_chip)
+    d, f, e = 2048, 1408, 8
+    hlo = _compile(moe_experts_pallas, s((rows, d), jnp.bfloat16),
+                   s((rows, e), jnp.float32), s((e, d, f), jnp.bfloat16),
+                   s((e, d, f), jnp.bfloat16), s((e, f, d), jnp.bfloat16))
+    assert "tpu_custom_call" in hlo and "%moe_experts" in hlo
+
+
+def _latent_step_hlo(one_chip, program):
+    """``program`` of the engine for Moonlight-16B-A3B's chip share at its
+    published widths, cut to two layers (the dense one and one MoE layer),
+    128 slots of 1024 in pages of 16, chunks of 32, the pool donated.
+    Returns (HLO text, the latent pool's HLO shape)."""
+    from repro.configs import get_config
+    from repro.core.lut import DENSE
+    from repro.models.model import Model
+    from repro.serve.engine import step_programs
+    cfg = get_config("moonlight-16b-a3b-ep8").replace(num_layers=2)
+    model = Model(cfg)
+    qc = DENSE.replace(flash="pallas")
+    s = _spec(one_chip)
+    on_chip = lambda tree: jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), qc)))
+    kv = on_chip(jax.eval_shape(lambda: model.init_paged_cache(
+        ML_SLOTS, 1024, PAGE, ML_SLOTS * PAGES_PER_SLOT)))
+    prefill_chunk, decode_step, _ = step_programs(model, qc)
+    table = s((ML_SLOTS, PAGES_PER_SLOT), jnp.int32)
+    if program == "decode_step":
+        fn, args = decode_step, (s((ML_SLOTS, 1), jnp.int32), kv, table,
+                                 s((ML_SLOTS,), jnp.int32))
+    else:
+        scalar = s((), jnp.int32)
+        fn, args = prefill_chunk, (s((1, 32), jnp.int32), kv, table,
+                                   scalar, scalar, scalar)
+    hlo = jax.jit(fn, donate_argnums=(2,)).lower(
+        params, *args).compile().as_text()
+    assert set(kv) == {"latent"} and kv["latent"].dtype == jnp.bfloat16
+    return hlo, "bf16[" + ",".join(map(str, kv["latent"].shape)) + "]"
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_latent_step_writes_rows_in_place(one_chip, monkeypatch, program):
+    """Moonlight's steps write their latent rows into the donated pool
+    where it lies (no whole-pool copy or scatter, the pool handed back as
+    an output), and never expand per-head keys or values over a slot's
+    positions: no result holds 1024 positions by 16 heads by a head
+    width (128 no-RoPE or value, 192 query/key)."""
+    import re
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo, pool = _latent_step_hlo(one_chip, program)
+    assert hlo.startswith(f"HloModule jit_{program},")
+    made = re.compile(r"(?:ROOT )?%[\w.-]+ = " + re.escape(pool)
+                      + r"\{[^}]*\} ([\w-]+)\(")
+    comps = _computations(hlo)
+    ops = [m.group(1) for body in comps.values()
+           for m in map(made.match, body) if m]
+    assert "dynamic-update-slice" in ops
+    relayouts = [op for op in ops if op.startswith("copy") or op == "scatter"]
+    assert not relayouts, relayouts
+    header = hlo.split("entry_computation_layout=", 1)[0]
+    aliased = {int(n) for n in re.findall(r"\{\d+\}: \((\d+), ", header)}
+    pool_params = {int(m.group(1)) for ins in comps["ENTRY"]
+                   for m in [re.search(re.escape(pool)
+                                       + r"\{[^}]*\} parameter\((\d+)\)",
+                                       ins)] if m}
+    assert len(pool_params) == 1 and pool_params <= aliased
+    shape = re.compile(r"= \w+\[([\d,]+)\]")
+    for body in comps.values():
+        for ins in body:
+            m = shape.search(ins)
+            dims = set(map(int, m.group(1).split(","))) if m else set()
+            assert not ({1024, 16} <= dims and dims & {128, 192}), ins[:120]
+
+
+def test_latent_decode_step_names_its_kernels(one_chip, monkeypatch):
+    """The decode step holds one latent-attention call per layer loop
+    (the dense layer's and the MoE layers'), which the benchmark's
+    ``mla_decode`` matcher claims, and one routed-expert call, which the
+    ``moe_ffn`` matcher claims; neither claims the shared experts' call,
+    the flash kernel's name or the ops that take their results."""
+    from pathlib import Path
+    monkeypatch.syspath_prepend(
+        str(Path(__file__).resolve().parents[1] / "bench"))
+    from work import mla_decode, moe_ffn
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo, _ = _latent_step_hlo(one_chip, "decode_step")
+    ins = [i for body in _computations(hlo).values() for i in body
+           if i.startswith(("%", "ROOT %"))]
+    mla = [i for i in ins if mla_decode.matches(i)]
+    moe = [i for i in ins if moe_ffn.matches(i)]
+    assert len(mla) == 2 and all("%mla_decode." in i.split(" = ")[0]
+                                 for i in mla)
+    assert len(moe) == 1 and "%moe_experts." in moe[0].split(" = ")[0]
+    assert any("%moe_shared." in i.split(" = ")[0] for i in ins)
+    assert not any("flash_splits" in i for i in ins)
