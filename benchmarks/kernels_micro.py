@@ -73,8 +73,9 @@ def _bench_flash_decode() -> None:
     ks = {}
     for i, nm in enumerate(("k", "v", "q", "kn", "vn")):
         ks[nm] = jax.random.fold_in(key, i)
-    k_pages = jax.random.normal(ks["k"], (pool, ps, kvh, d)) * 0.3
-    v_pages = jax.random.normal(ks["v"], (pool, ps, kvh, d)) * 0.3
+    shape = (1, pool, kvh, ps, d)                      # one-layer stack
+    k_pages = jax.random.normal(ks["k"], shape) * 0.3
+    v_pages = jax.random.normal(ks["v"], shape) * 0.3
     q = jax.random.normal(ks["q"], (b, 1, h, d))
     k_new = jax.random.normal(ks["kn"], (b, 1, kvh, d)) * 0.3
     v_new = jax.random.normal(ks["vn"], (b, 1, kvh, d)) * 0.3
@@ -82,8 +83,9 @@ def _bench_flash_decode() -> None:
     pos = jnp.array([np_ * ps - 1] * b, jnp.int32)     # pos 8191: full ctx
 
     def gather(q, kp, vp, kn, vn, ph, po):
-        view_k = kp[ph].reshape(b, np_ * ps, kvh, d)   # the HBM gather
-        view_v = vp[ph].reshape(b, np_ * ps, kvh, d)
+        view = lambda t: jnp.swapaxes(t[0][ph], 2, 3).reshape(
+            b, np_ * ps, kvh, d)                       # the HBM gather
+        view_k, view_v = view(kp), view(vp)
         return _sdpa_decode_combine(q, view_k, view_v, kn, vn, po, 0, 0)
 
     def flash(q, kp, vp, kn, vn, ph, po):
@@ -114,11 +116,10 @@ def _bench_flash_decode() -> None:
     flash_p = jax.jit(lambda q_, kp, vp, kn, vn, ph, po: flash_decode_paged(
         q_, kp, vp, kn, vn, ph, po, impl="pallas",
         interpret=jax.default_backend() != "tpu"))
-    args_p = (sq, k_pages[:s_pool], v_pages[:s_pool], k_new[:sb],
+    args_p = (sq, k_pages[:, :s_pool], v_pages[:, :s_pool], k_new[:sb],
               v_new[:sb], s_phys, s_pos)
     out_p = flash_p(*args_p)
-    ref_p = flash_j(sq, k_pages[:s_pool], v_pages[:s_pool], k_new[:sb],
-                    v_new[:sb], s_phys, s_pos)
+    ref_p = flash_j(*args_p)
     diff_p = float(jnp.max(jnp.abs(out_p - ref_p)))
     assert diff_p < 2e-4, f"pallas kernel diverged from ref: {diff_p}"
     t_p = time_jax(flash_p, *args_p, warmup=1, iters=3)

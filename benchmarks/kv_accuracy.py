@@ -191,7 +191,7 @@ def exact_cover_bench(model, params) -> None:
 
     # every row the run READS: positions [0, p + n_new - 1)
     t_rows = p + n_new - 1
-    rows = {key: kv[key][:, np.arange(4)].reshape(
+    rows = {key: jnp.swapaxes(kv[key][:, np.arange(4)], 2, 3).reshape(
         model.cfg.num_layers, 32, model.cfg.num_kv_heads,
         model.cfg.head_dim)[:, :t_rows] for key in ("k", "v")}
     cb = KVCodebook.from_rows(rows["k"], rows["v"])

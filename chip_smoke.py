@@ -353,8 +353,7 @@ def layer_parity(model, params, qc, prompt, batch_size: int = 8):
         kw = {}
         if decode:
             kw = dict(q_offset=positions, decode_slab=True, paged_phys=phys,
-                      flash_impl=flash,
-                      cache={k: a[li] for k, a in pool.items()})
+                      flash_impl=flash, cache=dict(pool, layer=li))
         a, _, _ = attention(p["attn"], h, cfg, q, **kw)
         f, _ = mlp(p["mlp"], h + a, cfg, q)
         # the attention core: the same attention with wo = identity
@@ -438,7 +437,7 @@ def kernel_parity(cfg, seed: int, rows=(8, 32), page: int = 16,
     g = cfg.num_heads // kvh
     np_ = max_seq // page
     ks = jax.random.split(key, 6)
-    pool = (slots * np_ + 1, page, kvh, d)
+    pool = (2, slots * np_ + 1, kvh, page, d)   # two layers, head-major
     kp = jax.random.normal(ks[0], pool, jnp.bfloat16)
     vp = jax.random.normal(ks[1], pool, jnp.bfloat16)
     q = jax.random.normal(ks[2], (slots, 1, kvh * g, d), jnp.bfloat16)
@@ -446,10 +445,11 @@ def kernel_parity(cfg, seed: int, rows=(8, 32), page: int = 16,
     vn = jax.random.normal(ks[4], (slots, 1, kvh, d), jnp.bfloat16)
     pos = jax.random.randint(ks[5], (slots,), 1, max_seq - 1)
     phys = jnp.arange(slots * np_, dtype=jnp.int32).reshape(slots, np_)
-    got = flash_decode_paged(q, kp, vp, kn, vn, phys, pos, impl="pallas",
+    got = flash_decode_paged(q, kp, vp, kn, vn, phys, pos, layer=1,
+                             impl="pallas",
                              interpret=jax.default_backend() != "tpu")
     with jax.default_matmul_precision("highest"):
-        want = kref.flash_decode_ref(q, kp, vp, kn, vn, phys, pos)
+        want = kref.flash_decode_ref(q, kp, vp, kn, vn, phys, pos, layer=1)
     errs[f"flash_decode_kvh{kvh}_d{d}"] = rel_err(got, want)
     return errs
 

@@ -292,12 +292,13 @@ def _flash_entry(impl: str, kvq: bool = False):
         b, kvh, g, d, np_, ps = 2, 2, 2, 16, 4, 8
         nc = 4
         q = jnp.ones((b, 1, kvh * g, d))
-        if kvq:
-            kp = jnp.ones((np_ + 1, ps, kvh, nc), jnp.uint8)
-            cb = {"zk": jnp.ones((nc, 16, 4)), "zv": jnp.ones((nc, 16, 4)),
-                  "sk": jnp.ones((kvh,)), "sv": jnp.ones((kvh,))}
+        if kvq:              # stacked two-layer head-major pool + codebook
+            kp = jnp.ones((2, np_ + 1, kvh, ps, nc), jnp.uint8)
+            cb = {"zk": jnp.ones((2, nc, 16, 4)),
+                  "zv": jnp.ones((2, nc, 16, 4)),
+                  "sk": jnp.ones((2, kvh)), "sv": jnp.ones((2, kvh))}
         else:
-            kp = jnp.ones((np_ + 1, ps, kvh, d))
+            kp = jnp.ones((2, np_ + 1, kvh, ps, d))
             cb = None
         kn = jnp.ones((b, 1, kvh, d))
         phys = jnp.zeros((b, np_), jnp.int32)
@@ -305,7 +306,7 @@ def _flash_entry(impl: str, kvq: bool = False):
 
         def fn(q, kp, vp, kn, vn, phys, pos):
             return flash_decode_paged(q, kp, vp, kn, vn, phys, pos,
-                                      impl=impl, codebook=cb,
+                                      layer=1, impl=impl, codebook=cb,
                                       interpret=True)
         return fn, (q, kp, kp, kn, kn, phys, pos)
     return build

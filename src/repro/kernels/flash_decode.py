@@ -28,9 +28,10 @@ applied to attention:
 
 Three implementations share the same masks and split algebra:
 
-  ``pallas``  — the real kernel. Scalar-prefetched page table drives the
-                BlockSpec index map, so each (slot, split, page) grid
-                step DMAs exactly one physical page into VMEM.
+  ``pallas``  — the real kernel. Scalar-prefetched layer index and page
+                table drive the BlockSpec index map, so each (slot,
+                split, page) grid step DMAs exactly one physical page of
+                one layer into VMEM, straight out of the stacked pool.
   ``ref``     — XLA-native. Scores are computed against the *whole*
                 pool and gathered per slot (scores are ~8x smaller than
                 KV rows, so this moves far less HBM traffic than
@@ -39,6 +40,11 @@ Three implementations share the same masks and split algebra:
   (callers may also pick ``gather`` upstream — the legacy
   ``_paged_view`` + ``_sdpa_decode_combine`` path, see
   ``model.decode_paged``.)
+
+The pool is stored head-major, ``(L, P+1, KVH, page, D)``: a page block
+``(heads, page, D)`` is then whole (page, D) tiles in the pool's own
+row-major layout, so the kernel takes the stacked pool as it lies and
+no layer of it is sliced or relaid out before the call.
 
 The pure split-triple functions double as the property-test surface:
 ``tests/test_flash_decode.py`` checks split-count/order invariance and
@@ -117,18 +123,19 @@ def flash_decode_splits(qg: jax.Array, k_pages: jax.Array,
     """Per-split triples in plain JAX — the mid-level oracle.
 
     qg: (B, KVH, G, D) float32 queries, already scaled by D**-0.5.
-    k_pages/v_pages: (P+1, page, KVH, D) pool (last page = trash).
+    k_pages/v_pages: (P+1, KVH, page, D) one layer of the head-major
+    pool (last page = trash).
     phys: (B, NS*split_pages) physical page ids (trash-padded).
     pos/ks: (B,) int32; win: scalar int32 (0 = no window).
     Returns (m, l, acc) shaped (NS, B, KVH, G[, D]) float32.
     """
     b, kvh, g, d = qg.shape
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[2]
     nsp = phys.shape[1]
     ns = nsp // split_pages
     sl = split_pages * ps                                  # tokens / split
-    kg = k_pages[phys].reshape(b, ns, sl, kvh, d)
-    vg = v_pages[phys].reshape(b, ns, sl, kvh, d)
+    kg = jnp.swapaxes(k_pages[phys], 2, 3).reshape(b, ns, sl, kvh, d)
+    vg = jnp.swapaxes(v_pages[phys], 2, 3).reshape(b, ns, sl, kvh, d)
     kj = jnp.arange(ns * sl, dtype=jnp.int32).reshape(ns, sl)
     mask = _split_masks(pos[:, None, None], win, ks[:, None, None],
                         kj[None])                          # (B, NS, SL)
@@ -150,7 +157,8 @@ def flash_decode_splits(qg: jax.Array, k_pages: jax.Array,
 # Pallas kernel (phase 1: per-split triples, pages DMAed in place)
 # ---------------------------------------------------------------------------
 
-def _flash_kernel(phys_ref, pos_ref, win_ref, ks_ref,    # scalar prefetch
+def _flash_kernel(layer_ref, phys_ref, pos_ref, win_ref,
+                  ks_ref,                                # scalar prefetch
                   q_ref, k_ref, v_ref,                   # inputs
                   m_ref, l_ref, acc_ref, *, ps, sp):
     """One (slot, kv-head tile, split, page) grid step.
@@ -174,7 +182,7 @@ def _flash_kernel(phys_ref, pos_ref, win_ref, ks_ref,    # scalar prefetch
     mask = _split_masks(pos_ref[ib], win_ref[0], ks_ref[ib], kj)
 
     q = q_ref[0].astype(jnp.float32)                     # (bh, G, D)
-    k = jnp.transpose(k_ref[0].astype(jnp.float32), (1, 0, 2))  # (bh,ps,D)
+    k = k_ref[0, 0].astype(jnp.float32)                  # (bh, ps, D)
     sc = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                              preferred_element_type=jnp.float32)
     sc = jnp.where(mask, sc, NEG_INF)                    # (bh, G, ps)
@@ -182,7 +190,7 @@ def _flash_kernel(phys_ref, pos_ref, win_ref, ks_ref,    # scalar prefetch
     m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1))
     p = jnp.where(mask, jnp.exp(sc - m_new[..., None]), 0.0)
     alpha = jnp.exp(m_prev - m_new)
-    v = jnp.transpose(v_ref[0].astype(jnp.float32), (1, 0, 2))  # (bh,ps,D)
+    v = v_ref[0, 0].astype(jnp.float32)                  # (bh, ps, D)
     pv = jax.lax.dot_general(p, v, (((2,), (1,)), ((0,), (0,))),
                              preferred_element_type=jnp.float32)
     m_ref[0, 0] = m_new
@@ -190,31 +198,40 @@ def _flash_kernel(phys_ref, pos_ref, win_ref, ks_ref,    # scalar prefetch
     acc_ref[0, 0] = acc_ref[0, 0] * alpha[..., None] + pv
 
 
-def _splits_pallas(qg, k_pages, v_pages, phys, pos, win, ks,
+def _page_map(sp):
+    """Index map of a K/V page block ``(1, 1, bh, page, D)`` of the
+    stacked head-major pool: layer ``layer_ref[0]``, the physical page
+    the slot's page table holds for this grid step, kv-head tile ``ih``."""
+    def page_map(ib, ih, is_, ip, layer_ref, phys_ref, *_):
+        return (layer_ref[0], phys_ref[ib, is_ * sp + ip], ih, 0, 0)
+    return page_map
+
+
+def _splits_pallas(qg, k_pool, v_pool, layer, phys, pos, win, ks,
                    split_pages: int, block_heads: int,
                    interpret: bool = False):
-    """Phase-1 triples via ``pallas_call``. Same contract as
-    :func:`flash_decode_splits`; the page table is a scalar-prefetch
-    operand whose values drive the K/V BlockSpec index maps — each grid
-    step DMAs one physical page, nothing is ever gathered in HBM."""
+    """Phase-1 triples via ``pallas_call`` over layer ``layer`` of the
+    stacked pools ``(L, P+1, KVH, page, D)``. Same contract as
+    :func:`flash_decode_splits`; the layer index and the page table are
+    scalar-prefetch operands whose values drive the K/V BlockSpec index
+    maps — each grid step DMAs one physical page of that layer, nothing
+    is ever sliced or gathered in HBM."""
     b, kvh, g, d = qg.shape
-    ps = k_pages.shape[1]
+    ps = k_pool.shape[3]
     sp = split_pages
     ns = phys.shape[1] // sp
     bh = block_heads
     grid = (b, kvh // bh, ns, sp)
-
-    def page_map(ib, ih, is_, ip, phys_ref, *_):
-        return (phys_ref[ib, is_ * sp + ip], 0, ih, 0)
+    page_map = _page_map(sp)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bh, g, d),
                          lambda ib, ih, is_, ip, *_: (ib, ih, 0, 0)),
-            pl.BlockSpec((1, ps, bh, d), page_map),
-            pl.BlockSpec((1, ps, bh, d), page_map),
+            pl.BlockSpec((1, 1, bh, ps, d), page_map),
+            pl.BlockSpec((1, 1, bh, ps, d), page_map),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bh, g),
@@ -233,7 +250,12 @@ def _splits_pallas(qg, k_pages, v_pages, phys, pos, win, ks,
     kern = functools.partial(_flash_kernel, ps=ps, sp=sp)
     return pl.pallas_call(kern, grid_spec=grid_spec, out_shape=out_shape,
                           interpret=interpret, name=SPLITS_NAME)(
-        phys, pos, win, ks, qg, k_pages, v_pages)
+        _layer_operand(layer), phys, pos, win, ks, qg, k_pool, v_pool)
+
+
+def _layer_operand(layer):
+    """The layer index as the (1,) int32 scalar-prefetch operand."""
+    return jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
 
 
 # ---------------------------------------------------------------------------
@@ -245,7 +267,7 @@ def _splits_pallas(qg, k_pages, v_pages, phys, pos, win, ks,
 def _deq_tile(codes, rep, table, s):
     """In-kernel dequant of one code tile as two 2-D MXU matmuls.
 
-    codes (ps, bh, nc) int32; rep (nc, nc*c) spreads code ``k`` over
+    codes (bh, ps, nc) int32; rep (nc, nc*c) spreads code ``k`` over
     columns ``k*c .. k*c+c-1``; table (nc*c, D) is the block-diagonal
     codebook (row ``k*c+j`` holds centroid ``j`` of subspace ``k`` at
     lanes ``k*v .. k*v+v-1``); s (bh,) f32 per-head scales. The one-hot
@@ -254,10 +276,10 @@ def _deq_tile(codes, rep, table, s):
     is tile-aligned when ``ps`` is a multiple of 8.
     Returns fp K or V rows (bh, ps, D) — never round-tripped to HBM.
     """
-    ps_, bh, nc = codes.shape
+    bh, ps_, nc = codes.shape
     ncc = rep.shape[1]
     c = ncc // nc
-    ct = jnp.transpose(codes, (1, 0, 2)).reshape(bh * ps_, nc)
+    ct = codes.reshape(bh * ps_, nc)
     spread = jnp.dot(ct.astype(jnp.float32), rep,
                      preferred_element_type=jnp.float32)   # exact: ints < c
     j = jax.lax.broadcasted_iota(jnp.int32, (bh * ps_, ncc), 1) % c
@@ -276,7 +298,8 @@ def _deq_operands(z):
     return rep, table.reshape(nc * c, nc * v)
 
 
-def _flash_kernel_kvq(phys_ref, pos_ref, win_ref, ks_ref,   # scalar prefetch
+def _flash_kernel_kvq(layer_ref, phys_ref, pos_ref, win_ref,
+                      ks_ref,                               # scalar prefetch
                       q_ref, kc_ref, vc_ref, rep_ref, tk_ref, tv_ref,
                       sk_ref, sv_ref,                       # inputs
                       m_ref, l_ref, acc_ref, *, ps, sp):
@@ -298,7 +321,7 @@ def _flash_kernel_kvq(phys_ref, pos_ref, win_ref, ks_ref,   # scalar prefetch
     mask = _split_masks(pos_ref[ib], win_ref[0], ks_ref[ib], kj)
 
     q = q_ref[0].astype(jnp.float32)                     # (bh, G, D)
-    k = _deq_tile(kc_ref[0].astype(jnp.int32), rep_ref[...], tk_ref[...],
+    k = _deq_tile(kc_ref[0, 0].astype(jnp.int32), rep_ref[...], tk_ref[...],
                   sk_ref[:, 0])                          # (bh, ps, D)
     sc = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                              preferred_element_type=jnp.float32)
@@ -307,7 +330,7 @@ def _flash_kernel_kvq(phys_ref, pos_ref, win_ref, ks_ref,   # scalar prefetch
     m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1))
     p = jnp.where(mask, jnp.exp(sc - m_new[..., None]), 0.0)
     alpha = jnp.exp(m_prev - m_new)
-    v = _deq_tile(vc_ref[0].astype(jnp.int32), rep_ref[...], tv_ref[...],
+    v = _deq_tile(vc_ref[0, 0].astype(jnp.int32), rep_ref[...], tv_ref[...],
                   sv_ref[:, 0])                          # (bh, ps, D)
     pv = jax.lax.dot_general(p, v, (((2,), (1,)), ((0,), (0,))),
                              preferred_element_type=jnp.float32)
@@ -316,25 +339,24 @@ def _flash_kernel_kvq(phys_ref, pos_ref, win_ref, ks_ref,   # scalar prefetch
     acc_ref[0, 0] = acc_ref[0, 0] * alpha[..., None] + pv
 
 
-def _splits_pallas_kvq(qg, kc_pages, vc_pages, zk, zv, sk, sv,
+def _splits_pallas_kvq(qg, kc_pool, vc_pool, layer, zk, zv, sk, sv,
                        phys, pos, win, ks,
                        split_pages: int, block_heads: int,
                        interpret: bool = False):
-    """Phase-1 triples off a QUANTIZED pool. Same grid/page-map contract
-    as :func:`_splits_pallas`; the codebook tables (in the
+    """Phase-1 triples off a QUANTIZED pool ``(L, P+1, KVH, page, nc)``
+    at layer ``layer``. Same grid/page-map contract as
+    :func:`_splits_pallas`; one layer's codebook tables (in the
     :func:`_deq_operands` form) and per-head scales ride along as small
     VMEM-resident operands (tables whole, sk/sv tiled with the kv-head
     grid axis)."""
     b, kvh, g, d = qg.shape
-    ps = kc_pages.shape[1]
+    ps = kc_pool.shape[3]
     nc, c, v = zk.shape
     sp = split_pages
     ns = phys.shape[1] // sp
     bh = block_heads
     grid = (b, kvh // bh, ns, sp)
-
-    def page_map(ib, ih, is_, ip, phys_ref, *_):
-        return (phys_ref[ib, is_ * sp + ip], 0, ih, 0)
+    page_map = _page_map(sp)
 
     def table_map(ib, ih, is_, ip, *_):
         return (0, 0)
@@ -343,13 +365,13 @@ def _splits_pallas_kvq(qg, kc_pages, vc_pages, zk, zv, sk, sv,
         return (ih, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bh, g, d),
                          lambda ib, ih, is_, ip, *_: (ib, ih, 0, 0)),
-            pl.BlockSpec((1, ps, bh, nc), page_map),
-            pl.BlockSpec((1, ps, bh, nc), page_map),
+            pl.BlockSpec((1, 1, bh, ps, nc), page_map),
+            pl.BlockSpec((1, 1, bh, ps, nc), page_map),
             pl.BlockSpec((nc, nc * c), table_map),
             pl.BlockSpec((nc * c, d), table_map),
             pl.BlockSpec((nc * c, d), table_map),
@@ -375,7 +397,7 @@ def _splits_pallas_kvq(qg, kc_pages, vc_pages, zk, zv, sk, sv,
     kern = functools.partial(_flash_kernel_kvq, ps=ps, sp=sp)
     return pl.pallas_call(kern, grid_spec=grid_spec, out_shape=out_shape,
                           interpret=interpret, name=SPLITS_NAME + "_kvq")(
-        phys, pos, win, ks, qg, kc_pages, vc_pages,
+        _layer_operand(layer), phys, pos, win, ks, qg, kc_pool, vc_pool,
         rep, table_k, table_v,
         sk.reshape(kvh, 1).astype(jnp.float32),
         sv.reshape(kvh, 1).astype(jnp.float32))
@@ -391,14 +413,17 @@ def _flash_xla_kvq(qg, kc_pages, vc_pages, zk, zv, sk, sv,
     bytes/token/head of HBM traffic instead of ``4*D``). The value side
     pools probability mass per (subspace, centroid) first and applies
     each centroid vector once — fp K/V rows are never materialised, not
-    even transiently. Returns the cache triple (m, l, acc)."""
+    even transiently. kc_pages/vc_pages: one layer of the head-major
+    code pool, (P+1, KVH, page, nc). Returns the cache triple (m, l,
+    acc)."""
     b, kvh, g, d = qg.shape
-    ps = kc_pages.shape[1]
+    ps = kc_pages.shape[2]
     nc, c, v = zk.shape
     np_ = phys.shape[1]
     t = np_ * ps
-    kc = kc_pages[phys].reshape(b, t, kvh, nc)           # uint8 gathers —
-    vc = vc_pages[phys].reshape(b, t, kvh, nc)           # 4-16x less HBM
+    kc = jnp.swapaxes(kc_pages[phys], 2, 3).reshape(   # uint8 gathers —
+        b, t, kvh, nc)                                 # 4-16x less HBM
+    vc = jnp.swapaxes(vc_pages[phys], 2, 3).reshape(b, t, kvh, nc)
     # score LUT: fold the per-head K scale into the query
     qs = (qg * sk[None, :, None, None]).reshape(b, kvh, g, nc, v)
     lut_k = jnp.einsum("bkgsv,scv->bkgsc", qs, zk.astype(jnp.float32),
@@ -435,12 +460,13 @@ def _flash_xla(qg, k_pages, v_pages, phys, pos, win, ks):
     probabilities for the V contraction) reads each pool page once and
     never materialises a dense KV view. Returns the combined cache
     triple (m, l, acc) shaped (B, KVH, G[, D]) — the caller folds the
-    self term.
+    self term. k_pages/v_pages: one layer of the head-major pool, (P+1,
+    KVH, page, D).
     """
     b, kvh, g, d = qg.shape
-    p1, ps = k_pages.shape[0], k_pages.shape[1]
+    p1, ps = k_pages.shape[0], k_pages.shape[2]
     np_ = phys.shape[1]
-    sc_all = jnp.einsum("bkgd,ptkd->bptkg", qg, k_pages,
+    sc_all = jnp.einsum("bkgd,pktd->bptkg", qg, k_pages,
                         preferred_element_type=jnp.float32)
     sc = jnp.take_along_axis(
         sc_all, phys[:, :, None, None, None], axis=1)    # (B,NP,ps,KVH,G)
@@ -458,7 +484,7 @@ def _flash_xla(qg, k_pages, v_pages, phys, pos, win, ks):
     # pages live in distinct batch rows so they never collide.
     p_all = jnp.zeros((b, p1, ps, kvh, g), jnp.float32)
     p_all = p_all.at[jnp.arange(b)[:, None], phys].add(p)
-    acc = jnp.einsum("bptkg,ptkd->bkgd", p_all,
+    acc = jnp.einsum("bptkg,pktd->bkgd", p_all,
                      v_pages.astype(jnp.float32),
                      preferred_element_type=jnp.float32)
     return m, l, acc
@@ -470,35 +496,38 @@ def _flash_xla(qg, k_pages, v_pages, phys, pos, win, ks):
 
 def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                        k_new: jax.Array, v_new: jax.Array,
-                       phys: jax.Array, positions, *,
+                       phys: jax.Array, positions, *, layer=0,
                        window=0, kv_start=0, impl: str = "ref",
                        codebook: Optional[dict] = None,
                        split_pages: Optional[int] = None,
                        block_heads: Optional[int] = None,
                        interpret: bool = False) -> jax.Array:
-    """Single-token paged decode, numerically matching
-    ``layers._sdpa_decode_combine`` over the gathered view.
+    """Single-token paged decode over layer ``layer`` of the stacked pool,
+    read where it lies, numerically matching
+    ``layers._sdpa_decode_combine`` over that layer's gathered view.
 
-    q (B,1,H,D); k_pages/v_pages (P+1, page, KVH, D) — one layer's slice
-    of the pool, last page = trash; k_new/v_new (B,1,KVH,D) the fresh
-    token (NOT yet in the pool — the caller scatters it afterwards, and
-    its self term is always computed from the fp values, so the newest
-    token is exact even on a quantized pool).
+    q (B,1,H,D); k_pages/v_pages (L, P+1, KVH, page, D) — the stacked
+    head-major pool of every layer, last page = trash; layer: this
+    layer's index (a traced scalar in the model's layer scan);
+    k_new/v_new (B,1,KVH,D) the fresh token (NOT yet in the pool — the
+    caller writes it afterwards, and its self term is always computed
+    from the fp values, so the newest token is exact even on a quantized
+    pool).
     phys (B, NP) physical page ids, already trash-redirected.
     positions (B,) int32 per-slot lengths (-1 = inactive lane: output is
     the garbage ``v_new`` row, discarded by the caller — same contract
     as ``_sdpa_decode_combine``). window/kv_start: scalar or (B,).
-    codebook: one layer's slice of the KV codebook pytree (``{"zk":
-    (nc,c,v), "zv": ..., "sk": (KVH,), "sv": ...}``, see
-    core/kv_codebook.py) — when given, k_pages/v_pages are uint8 CODE
-    pools ``(P+1, page, KVH, nc)`` and the impl dequantizes in VMEM
-    (pallas) or LUT-accumulates (ref) without materialising fp K/V.
+    codebook: the stacked KV codebook pytree (``{"zk": (L,nc,c,v),
+    "zv": ..., "sk": (L,KVH), "sv": ...}``, see core/kv_codebook.py) —
+    when given, k_pages/v_pages are uint8 CODE pools ``(L, P+1, KVH,
+    page, nc)`` and the impl dequantizes in VMEM (pallas) or
+    LUT-accumulates (ref) without materialising fp K/V.
     impl: "pallas" | "ref". Returns (B, 1, H*D) in q.dtype.
     """
     b, s, h, d = q.shape
     if s != 1:
         raise ValueError(f"flash decode is single-token (got S={s})")
-    ps, kvh = k_pages.shape[1], k_pages.shape[2]
+    kvh, ps = k_pages.shape[2], k_pages.shape[3]
     msize = tp_size()
     if impl == "pallas" and msize > 1:
         if kvh % msize:
@@ -506,8 +535,8 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         # a Mosaic kernel is opaque to GSPMD: run it on each chip's
         # kv heads (the pool is head-sharded, parallel/sharding.py)
         return _flash_decode_tp(
-            q, k_pages, v_pages, k_new, v_new, phys, positions, window,
-            kv_start, codebook, split_pages, block_heads, interpret)
+            q, k_pages, v_pages, k_new, v_new, phys, positions, layer,
+            window, kv_start, codebook, split_pages, block_heads, interpret)
     g = h // kvh
     np_ = phys.shape[1]
     scale = d ** -0.5
@@ -515,9 +544,12 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     pos = jnp.broadcast_to(jnp.asarray(positions, jnp.int32), (b,))
     ks = jnp.broadcast_to(jnp.asarray(kv_start, jnp.int32), (b,))
     win = jnp.asarray(window, jnp.int32).reshape(-1)[:1]   # (1,) scalar
+    cb = (None if codebook is None else
+          {key: jax.lax.dynamic_index_in_dim(t, layer, 0, keepdims=False)
+           for key, t in codebook.items()})
 
     if impl == "pallas":
-        deq = 0 if codebook is None else 4
+        deq = 0 if cb is None else 4
         blk = select_blocks("flash_decode", b, np_, ps, d,
                             k_pages.dtype.itemsize, deq_itemsize=deq)
         sp = min(split_pages or blk.block_k, np_)
@@ -525,24 +557,26 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         pad = (-np_) % sp
         if pad:                       # trash-pad: kj >= NP*page >= pos
             phys = jnp.pad(phys, ((0, 0), (0, pad)),
-                           constant_values=k_pages.shape[0] - 1)
-        if codebook is None:
-            m, l, acc = _splits_pallas(qg, k_pages, v_pages, phys, pos,
-                                       win, ks, sp, bh, interpret=interpret)
+                           constant_values=k_pages.shape[1] - 1)
+        if cb is None:
+            m, l, acc = _splits_pallas(qg, k_pages, v_pages, layer, phys,
+                                       pos, win, ks, sp, bh,
+                                       interpret=interpret)
         else:
             m, l, acc = _splits_pallas_kvq(
-                qg, k_pages, v_pages, codebook["zk"], codebook["zv"],
-                codebook["sk"], codebook["sv"], phys, pos, win, ks,
-                sp, bh, interpret=interpret)
+                qg, k_pages, v_pages, layer, cb["zk"], cb["zv"], cb["sk"],
+                cb["sv"], phys, pos, win, ks, sp, bh, interpret=interpret)
         m, l, acc = reduce_splits(m, l, acc)
     elif impl == "ref":
-        if codebook is None:
-            m, l, acc = _flash_xla(qg, k_pages, v_pages, phys, pos,
+        at = lambda t: jax.lax.dynamic_index_in_dim(t, layer, 0,
+                                                    keepdims=False)
+        if cb is None:
+            m, l, acc = _flash_xla(qg, at(k_pages), at(v_pages), phys, pos,
                                    win[0], ks)
         else:
             m, l, acc = _flash_xla_kvq(
-                qg, k_pages, v_pages, codebook["zk"], codebook["zv"],
-                codebook["sk"], codebook["sv"], phys, pos, win[0], ks)
+                qg, at(k_pages), at(v_pages), cb["zk"], cb["zv"], cb["sk"],
+                cb["sv"], phys, pos, win[0], ks)
     else:
         raise ValueError(f"unknown flash impl {impl!r} (pallas | ref)")
 
@@ -563,25 +597,29 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
 
 
 def _flash_decode_tp(q, k_pages, v_pages, k_new, v_new, phys, positions,
-                     window, kv_start, codebook, split_pages, block_heads,
-                     interpret):
+                     layer, window, kv_start, codebook, split_pages,
+                     block_heads, interpret):
     """:func:`flash_decode_paged` (pallas) per ``model`` shard of heads."""
-    heads = P(None, None, "model", None)
+    heads = P(None, None, "model", None)            # (B, 1, heads, D)
+    pool = P(None, None, "model", None, None)       # (L, P+1, KVH, page, D)
     cb_spec = (None if codebook is None else
-               {k: P("model") if k in ("sk", "sv") else P()
+               {k: P(None, "model") if k in ("sk", "sv") else P()
                 for k in codebook})
 
-    def local(q, kp, vp, kn, vn, phys, pos, win, ks, cb):
+    def local(q, kp, vp, kn, vn, phys, pos, layer, win, ks, cb):
         return flash_decode_paged(
-            q, kp, vp, kn, vn, phys, pos, window=win, kv_start=ks,
-            impl="pallas", codebook=cb, split_pages=split_pages,
-            block_heads=block_heads, interpret=interpret)
+            q, kp, vp, kn, vn, phys, pos, layer=layer, window=win,
+            kv_start=ks, impl="pallas", codebook=cb,
+            split_pages=split_pages, block_heads=block_heads,
+            interpret=interpret)
     return jax.shard_map(
-        local, in_specs=(heads,) * 5 + (P(),) * 4 + (cb_spec,),
+        local, in_specs=(heads, pool, pool, heads, heads) + (P(),) * 5
+        + (cb_spec,),
         out_specs=P(None, None, "model"), check_vma=False)(
         q, k_pages, v_pages, k_new, v_new, phys,
-        jnp.asarray(positions, jnp.int32), jnp.asarray(window, jnp.int32),
-        jnp.asarray(kv_start, jnp.int32), codebook)
+        jnp.asarray(positions, jnp.int32), jnp.asarray(layer, jnp.int32),
+        jnp.asarray(window, jnp.int32), jnp.asarray(kv_start, jnp.int32),
+        codebook)
 
 
 def resolve_flash_impl(flash: str, on_tpu: Optional[bool] = None) -> str:

@@ -95,25 +95,28 @@ def lut_gemm_onehot(idx: jax.Array, lut: jax.Array,
 def flash_decode_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                      k_new: jax.Array, v_new: jax.Array,
                      phys: jax.Array, positions, window=0,
-                     kv_start=0) -> jax.Array:
+                     kv_start=0, layer=0) -> jax.Array:
     """Oracle for paged flash decode: gather the view, one full softmax.
 
     No online/split reductions at all — the (trusted) dense formulation
     the split-KV kernel must reproduce to fp32 tolerance.
 
-    q (B,1,H,D); k_pages/v_pages (P+1, page, KVH, D) one-layer pool
-    slice; k_new/v_new (B,1,KVH,D) the fresh token; phys (B, NP)
-    trash-redirected page ids; positions (B,) per-slot lengths (-1 =
-    inactive). Returns (B, 1, H*D) in q.dtype.
+    q (B,1,H,D); k_pages/v_pages (L, P+1, KVH, page, D) the stacked
+    head-major pool, read at ``layer``; k_new/v_new (B,1,KVH,D) the
+    fresh token; phys (B, NP) trash-redirected page ids; positions (B,)
+    per-slot lengths (-1 = inactive). Returns (B, 1, H*D) in q.dtype.
     """
     b, _, h, d = q.shape
-    ps, kvh = k_pages.shape[1], k_pages.shape[2]
+    kvh, ps = k_pages.shape[2], k_pages.shape[3]
     g = h // kvh
     np_ = phys.shape[1]
     t = np_ * ps
     qg = q.reshape(b, kvh, g, d).astype(jnp.float32)
-    kg = k_pages[phys].reshape(b, t, kvh, d).astype(jnp.float32)
-    vg = v_pages[phys].reshape(b, t, kvh, d).astype(jnp.float32)
+
+    def view(pool):                # (B, NP, KVH, page, D) -> (B, T, KVH, D)
+        pages = pool[layer][phys].astype(jnp.float32)
+        return jnp.swapaxes(pages, 2, 3).reshape(b, t, kvh, d)
+    kg, vg = view(k_pages), view(v_pages)
     scale = d ** -0.5
     kj = jnp.arange(t, dtype=jnp.int32)
     pos = jnp.broadcast_to(jnp.asarray(positions, jnp.int32), (b,))
@@ -141,22 +144,24 @@ def flash_decode_kvq_ref(q: jax.Array, kc_pages: jax.Array,
                          vc_pages: jax.Array, cb: dict,
                          k_new: jax.Array, v_new: jax.Array,
                          phys: jax.Array, positions, window=0,
-                         kv_start=0) -> jax.Array:
+                         kv_start=0, layer=0) -> jax.Array:
     """Oracle for the vector-quantized pool: dequantize-then-reference.
 
-    kc_pages/vc_pages (P+1, page, KVH, nc) uint8 code pools; cb is one
-    layer's codebook slice {"zk": (nc,c,v), "zv": ..., "sk": (KVH,),
-    "sv": ...}. Decodes the whole pool with plain advanced indexing (no
-    one-hot tricks, no LUT factoring) and delegates to the dense oracle
-    — the trusted semantics both the LUT-accumulate ref impl and the
-    in-kernel-dequant pallas impl must reproduce.
+    kc_pages/vc_pages (L, P+1, KVH, page, nc) stacked head-major uint8
+    code pools, read at ``layer``; cb is the stacked codebook {"zk":
+    (L,nc,c,v), "zv": ..., "sk": (L,KVH), "sv": ...}. Decodes the
+    layer's pool with plain advanced indexing (no one-hot tricks, no LUT
+    factoring) and delegates to the dense oracle — the trusted semantics
+    both the LUT-accumulate ref impl and the in-kernel-dequant pallas
+    impl must reproduce.
     """
-    def deq(codes, z, s):
+    def deq(codes, z, s):                 # codes (P+1, KVH, page, nc)
         nc = z.shape[0]
         sub = z.astype(jnp.float32)[jnp.arange(nc), codes.astype(jnp.int32)]
         rows = sub.reshape(*codes.shape[:-1], -1)
-        return rows * s.astype(jnp.float32)[:, None]
-    k_pages = deq(kc_pages, cb["zk"], cb["sk"])
-    v_pages = deq(vc_pages, cb["zv"], cb["sv"])
-    return flash_decode_ref(q, k_pages, v_pages, k_new, v_new, phys,
-                            positions, window=window, kv_start=kv_start)
+        return rows * s.astype(jnp.float32)[:, None, None]
+    k_pages = deq(kc_pages[layer], cb["zk"][layer], cb["sk"][layer])
+    v_pages = deq(vc_pages[layer], cb["zv"][layer], cb["sv"][layer])
+    return flash_decode_ref(q, k_pages[None], v_pages[None], k_new, v_new,
+                            phys, positions, window=window,
+                            kv_start=kv_start)

@@ -90,11 +90,12 @@ def fit_vmem(block_n: int, block_k: int, c: int,
 def flash_head_tile(kvh: int, cap: int) -> int:
     """kv-head tile of the paged flash-decode kernel for ``kvh`` heads.
 
-    The tile is the second-minor dim of the K/V page block ``(page,
-    heads, head_dim)``, which the TPU compiler accepts only as a multiple
-    of 8 or as all ``kvh`` heads. Returns the largest such divisor of
-    ``kvh`` that is <= ``cap`` (the VMEM bound from :func:`select_blocks`),
-    else the smallest legal tile.
+    The tile is the leading dim of the K/V page block ``(heads, page,
+    head_dim)`` of the head-major pool, so the compiler takes any divisor
+    of ``kvh``; tiles stay a multiple of 8 or all ``kvh`` heads, which
+    keeps the grid's step count, and with it the per-step overhead, low.
+    Returns the largest such divisor of ``kvh`` that is <= ``cap`` (the
+    VMEM bound from :func:`select_blocks`), else the smallest such tile.
     """
     legal = [d for d in range(8, kvh, 8) if kvh % d == 0] + [kvh]
     fit = [d for d in legal if d <= cap]
@@ -124,7 +125,7 @@ def select_blocks(kind: str, m: int, nc: int, c: int,
     size (tokens), n = head_dim, itemsize = KV pool bytes/elt. The
     returned block_n is the kv-head tile bound (halved until the double-
     buffered K+V page tile fits VMEM; :func:`flash_head_tile` turns it
-    into a tile the compiler accepts) and block_k the pages per split.
+    into the kernel's tile) and block_k the pages per split.
     """
     cfg = _TABLE[regime(m)][kind]
     if kind == "flash_decode":

@@ -491,10 +491,11 @@ def attention(p: Params, x: jax.Array, cfg, qc: QuantConfig,
         prompts, so rows [0, pad_len) hold pad garbage that must never be
         attended; see docs/serving.md).
       paged_phys: (B, NP) trash-redirected physical page ids. When set
-        (single-token ``decode_slab`` only), ``cache`` is one layer's
-        slice of the paged POOL ``{"k": (P+1, page, KVH, HD), ...}`` and
-        decode runs the flash kernel straight off the pages — no dense
-        per-slot view exists (see kernels/flash_decode.py).
+        (single-token ``decode_slab`` only), ``cache`` is the stacked
+        paged POOL with this layer's index, ``{"k": (L, P+1, KVH, page,
+        HD), "v": ..., "layer": li}``, and decode runs the flash kernel
+        straight off that layer's pages — no slice of the pool and no
+        dense per-slot view exists (see kernels/flash_decode.py).
       flash_impl: "pallas" | "ref" — concrete flash impl (the "auto" /
         "gather" resolution happens in ``model.decode_paged``).
 
@@ -525,17 +526,19 @@ def attention(p: Params, x: jax.Array, cfg, qc: QuantConfig,
         v = v.reshape(b, s, kvh, hd)
     if decode_slab and cache is not None and cfg.head_layout != "hd":
         # vector-quantized pool: cache["k"/"v"] are uint8 codes and the
-        # per-layer codebook slice rides along; the fresh slab stays fp
+        # codebook rides along; the fresh slab stays fp
         # (model._encode_rows quantizes at the scatter site) and the
         # kernel dequantizes / LUT-accumulates the pool in place.
         cb = cache.get("codebook")
         if s == 1:
             if paged_phys is not None:
-                # paged flash decode: cache is the raw page pool slice;
-                # the kernel walks it through the page table in place.
+                # paged flash decode: cache is the raw stacked page pool;
+                # the kernel walks this layer of it through the page
+                # table in place.
                 out = flash_decode_paged(
                     q, cache["k"], cache["v"], k, v, paged_phys,
-                    q_offset, window=window, kv_start=kv_start,
+                    q_offset, layer=cache["layer"], window=window,
+                    kv_start=kv_start,
                     impl=flash_impl, codebook=cb,
                     interpret=jax.default_backend() != "tpu")
             else:

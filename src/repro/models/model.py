@@ -192,10 +192,11 @@ class Model:
           (B, S, KVH, HD) fresh-KV slab. Attention families only;
           requires ``return_slabs``.
         paged_phys / flash_impl: single-token decode only — ``cache`` is
-          the raw paged POOL (``{"k": (L, P+1, page, KVH, HD), ...}``)
-          and each layer's attention runs the flash-decode kernel
-          through the page table instead of over a gathered view (see
-          ``decode_paged`` and kernels/flash_decode.py).
+          the raw paged POOL (``{"k": (L, P+1, KVH, page, HD), ...}``)
+          and each layer's attention runs the flash-decode kernel over
+          it at the layer's index, through the page table, instead of
+          over a gathered view (see ``decode_paged`` and
+          kernels/flash_decode.py).
         live / return_load: MoE models — ``live`` (B*S,) marks the tokens
           whose routed (token, choice) pairs are counted, and the fifth
           return value is the count per MoE layer and held expert
@@ -225,8 +226,8 @@ class Model:
                          stack=None, local=None):
                 src = cache if slab_mode else c_full
                 c_l = None
-                if slab_mode and cfg.mla and paged_phys is not None:
-                    # the latent pool is read in place at this layer's
+                if slab_mode and paged_phys is not None:
+                    # the page pool is read in place at this layer's
                     # index: no per-layer slice of it is ever made
                     c_l = dict(src, layer=li)
                 elif src is not None:
@@ -646,18 +647,21 @@ class Model:
         """Physical cache storage for the paged serving engine.
 
         Attention families return a page pool ``{"k": (L, num_pages+1,
-        page_size, KVH, HD), "v": ...}`` — one extra *trash* page (the
-        last id) absorbs writes from padded / inactive positions. Latent
-        attention (``cfg.mla``) keeps one row a position instead, the
-        latent and its RoPE key: ``{"latent": (L, num_pages+1, page_size,
-        cfg.latent_row)}``, under the same page table. SSM
-        state is O(1) per sequence, so it stays slot-indexed
+        KVH, page_size, HD), "v": ...}`` — one extra *trash* page (the
+        last id) absorbs writes from padded / inactive positions. Pages
+        are head-major: a page's rows for one kv head are contiguous
+        (page_size, HD) tiles, so the TPU stores the pool row-major for
+        every KVH and the flash kernel reads a layer of it where it
+        lies. Latent attention (``cfg.mla``) keeps one row a position
+        instead, the latent and its RoPE key: ``{"latent": (L,
+        num_pages+1, page_size, cfg.latent_row)}``, under the same page
+        table. SSM state is O(1) per sequence, so it stays slot-indexed
         (``(L, num_slots, ...)``) and is recycled on eviction; the hybrid
         family keeps its few shared-attention invocations slot-dense.
 
         codebook: optional :class:`repro.core.kv_codebook.KVCodebook` —
         the pool then stores uint8 per-subspace centroid indices
-        ``(L, num_pages+1, page_size, KVH, nc)`` instead of fp rows, and
+        ``(L, num_pages+1, KVH, page_size, nc)`` instead of fp rows, and
         the codebook pytree rides the cache under ``"codebook"`` (every
         paged entry point detects quantization by that key). Attention
         families only.
@@ -685,7 +689,7 @@ class Model:
                         f"codebook (L={codebook.num_layers}, "
                         f"HD={codebook.head_dim}) does not match model "
                         f"(L={l}, HD={hd})")
-                shape = (l, num_pages + 1, page_size, kvh, codebook.nc)
+                shape = (l, num_pages + 1, kvh, page_size, codebook.nc)
                 # each cache owns PRIVATE copies of the codebook leaves:
                 # the serving jits donate the cache pytree, and donation
                 # deletes buffers — sharing one KVCodebook's arrays across
@@ -695,7 +699,7 @@ class Model:
                 return {"k": jnp.zeros(shape, jnp.uint8),
                         "v": jnp.zeros(shape, jnp.uint8),
                         CODEBOOK_KEY: cb_tree}
-            shape = (l, num_pages + 1, page_size, kvh, hd)
+            shape = (l, num_pages + 1, kvh, page_size, hd)
             return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
         conv_dim = cfg.d_inner + 2 * cfg.ssm_ngroups * cfg.ssm_state
         mamba = {
@@ -717,8 +721,8 @@ class Model:
                          "v": jnp.zeros(shape, dtype)}}
 
     def _paged_view(self, kv: Params, phys: jax.Array):
-        """Gather pages into a dense (L, B, NP*page, ...) view of each
-        pool leaf (``k``/``v``: (.., KVH, HD); ``latent``: (.., W)).
+        """Gather pages into a dense (L, B, NP*page, KVH, HD) view of the
+        head-major ``k``/``v`` pools.
 
         phys: (B, NP) physical page ids (already trash-redirected).
         A quantized pool (``"codebook"`` in kv) is gathered as uint8
@@ -728,9 +732,10 @@ class Model:
         dense attention math unchanged."""
         b, np_ = phys.shape
 
-        def view(pages):
-            l, _, ps, *row = pages.shape
-            return pages[:, phys].reshape(l, b, np_ * ps, *row)
+        def view(pages):                   # (L, P+1, KVH, page, W)
+            l, _, kvh, ps, w = pages.shape
+            return jnp.swapaxes(pages[:, phys], 3, 4).reshape(
+                l, b, np_ * ps, kvh, w)
         cb = kv.get(CODEBOOK_KEY)
         if cb is None:
             return {key: view(kv[key]) for key in _pool_keys(kv)}
@@ -1056,30 +1061,33 @@ def _pool_keys(kv: Params):
 
 
 def _trash_and_page(kv: Params):
-    """(trash page id, page size) of a paged attention cache."""
+    """(trash page id, page size) of a paged attention cache (the page
+    axis is second to last in a K/V and in a latent pool)."""
     pool = kv[_pool_keys(kv)[0]]
-    return pool.shape[1] - 1, pool.shape[2]
+    return pool.shape[1] - 1, pool.shape[-2]
 
 
 def _write_rows(pool: jax.Array, rows: jax.Array, tgt: jax.Array,
                 off: jax.Array) -> jax.Array:
     """Write fresh K/V rows into a page pool in place.
 
-    pool: (L, P+1, page, *row) (row: (KVH, W) of a K/V pool, (W,) of a
-    latent pool); rows: (L, *idx, *row) with ``tgt`` (page ids) and
-    ``off`` (offsets in the page) of shape ``idx``. Row i
-    goes to ``pool[:, tgt[i], off[i]]`` by one ``dynamic_update_slice``:
+    pool: (L, P+1, *heads, page, W) (heads: (KVH,) of a head-major K/V
+    pool, () of a latent pool); rows: (L, *idx, *heads, W) with ``tgt``
+    (page ids) and ``off`` (offsets in the page) of shape ``idx``. Row i
+    goes to page ``tgt[i]``, offset ``off[i]`` of every head by one
+    ``dynamic_update_slice``:
     the TPU runs those in the pool's stored layout on the donated buffer,
     where a scatter would relayout the whole pool into and out of its
     own. Live targets never collide; dead rows all land on the trash
     page, which nothing reads, so their order does not matter. tgt <= P
     and off < page, so no start index is ever clamped."""
-    l, row = pool.shape[0], pool.shape[3:]
-    rows = rows.reshape(l, -1, 1, 1, *row).astype(pool.dtype)
+    l, heads, w = pool.shape[0], pool.shape[2:-2], pool.shape[-1]
+    rows = rows.reshape(l, -1, 1, *heads, 1, w).astype(pool.dtype)
     tgt, off = tgt.reshape(-1), off.reshape(-1)
+    zeros = (0,) * len(heads)
     for i in range(rows.shape[1]):
         pool = jax.lax.dynamic_update_slice(
-            pool, rows[:, i], (0, tgt[i], off[i]) + (0,) * len(row))
+            pool, rows[:, i], (0, tgt[i]) + zeros + (off[i], 0))
     return pool
 
 

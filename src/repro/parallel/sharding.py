@@ -206,7 +206,7 @@ def paged_cache_pspecs(cfg: ModelConfig, mesh: Mesh,
     """PartitionSpecs for the PAGED serving cache (Model.init_paged_cache).
 
     Layout (see docs/serving.md): attention families store KV as a physical
-    page pool ``(L, num_pages+1, page_size, KVH, HD)``. The pool — pages,
+    page pool ``(L, num_pages+1, KVH, page_size, HD)``. The pool — pages,
     trash page included — is REPLICATED over the data axes: data-parallel
     serving is replica-level (one engine + page table per replica group,
     ``repro.serve.router``), so within one engine only tensor parallelism
@@ -215,7 +215,7 @@ def paged_cache_pspecs(cfg: ModelConfig, mesh: Mesh,
     ``model`` (falling back to replicated on non-divisible dims). The page
     table, positions and tokens are host-managed and replicated.
 
-    ``quantized``: the pool holds uint8 codes ``(L, P+1, page, KVH, nc)``
+    ``quantized``: the pool holds uint8 codes ``(L, P+1, KVH, page, nc)``
     plus the codebook pytree. kv heads still shard over ``model`` when
     divisible, but the last dim is the SUBSPACE axis, not head_dim — the
     head_dim fallback does not apply (a centroid spans ``v`` contiguous
@@ -227,7 +227,7 @@ def paged_cache_pspecs(cfg: ModelConfig, mesh: Mesh,
     mh, md = _kv_model_axes(cfg, m, msize)
     if quantized:
         md = None                           # last dim = subspaces, whole
-    kv = P(None, None, None, mh, md)        # (L, P+1, page, KVH, HD|nc)
+    kv = P(None, None, mh, None, md)        # (L, P+1, KVH, page, HD|nc)
     if cfg.family in ("dense", "moe", "audio", "vlm"):
         if quantized:
             cbook = {"zk": P(), "zv": P(), "sk": P(), "sv": P()}
@@ -245,7 +245,8 @@ def paged_cache_pspecs(cfg: ModelConfig, mesh: Mesh,
     if cfg.family == "ssm":
         return mamba
     # hybrid: slot-dense shared-attn cache (n_inv, slots, T+1, KVH, HD)
-    return {"mamba": mamba, "attn": {"k": kv, "v": kv}}
+    dense = P(None, None, None, mh, md)
+    return {"mamba": mamba, "attn": {"k": dense, "v": dense}}
 
 
 def logical_to_sharding(specs, mesh: Mesh):

@@ -597,7 +597,7 @@ class PagedKVCache:
 
     ``data`` is the pytree returned by ``Model.init_paged_cache``:
 
-      * attention families: ``{"k": (L, P+1, page, KVH, HD), "v": ...}``
+      * attention families: ``{"k": (L, P+1, KVH, page, HD), "v": ...}``
         where ``P`` is the physical pool size and the final page is the
         trash page (see module docstring); latent attention keeps one
         ``{"latent": (L, P+1, page, W)}`` pool under the same table.
@@ -653,8 +653,8 @@ class PagedKVCache:
             return 0
         total = 0
         for key in self._page_keys():
-            t = self.data[key]          # (L, P+1, page, *row)
-            total += (t.size // (t.shape[1] * t.shape[2])
+            t = self.data[key]          # (L, P+1, [KVH,] page, W)
+            total += (t.size // (t.shape[1] * t.shape[-2])
                       * t.dtype.itemsize)
         return total
 

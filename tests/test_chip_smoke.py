@@ -117,13 +117,13 @@ with jax.set_mesh(mesh):
         pass
     else:
         raise AssertionError("vq_amm ran unsharded under a model axis")
-kp = jax.random.normal(k[4], (9, 8, 4, 16))
+kp = jax.random.normal(k[4], (2, 9, 4, 8, 16))  # 2 layers, head-major
 q = jax.random.normal(k[5], (2, 1, 8, 16))
 kn = jax.random.normal(k[6], (2, 1, 4, 16))
 phys = jnp.arange(8, dtype=jnp.int32).reshape(2, 4)
 pos = jnp.array([5, 20], jnp.int32)
 args = (q, kp, kp * 0.5, kn, kn * 2.0, phys, pos)
-flash = jax.jit(lambda *a: flash_decode_paged(*a, impl="pallas",
+flash = jax.jit(lambda *a: flash_decode_paged(*a, layer=1, impl="pallas",
                                               interpret=True))
 want = flash(*args)
 with jax.set_mesh(mesh):

@@ -4,9 +4,11 @@ Layers of evidence, innermost out:
   1. the split-triple algebra (combine/reduce) is invariant to split
      count and order, matches full softmax, and treats all-masked splits
      as the identity (property tests via hypothesis when available);
-  2. the kernel (both the Pallas grid and the XLA "ref" impl) matches
-     the full-softmax oracle ``kernels.ref.flash_decode_ref`` AND the
-     legacy gather path across GQA/window/kv_start/page-boundary grids;
+  2. the kernel (both the Pallas grid and the XLA "ref" impl), reading
+     one layer of a stacked head-major pool at its index, matches the
+     full-softmax oracle ``kernels.ref.flash_decode_ref`` AND the legacy
+     gather path over that layer's view across
+     GQA/window/kv_start/page-boundary/layer grids;
   3. model-level decode chains (dense, lut_infer, gemma-style
      GQA+sliding-window) are token-identical across impls;
   4. the serving engine under pool exhaustion + preemption produces
@@ -136,19 +138,25 @@ def test_combine_splits_identity_and_fold():
 # kernel-level parity (oracle + gather path)
 # ---------------------------------------------------------------------------
 
+# layers of the synthetic stacked pool; tests read its first and last
+LAYERS = 3
+
+
 def _mk_case(seed, slots, np_, ps, kvh, g, d, positions):
-    """Synthetic one-layer pool + page tables honouring the engine
-    invariant (pages covering pos+1 tokens allocated, rest trash).
+    """Synthetic stacked head-major pool ``(LAYERS, P+1, KVH, page, D)``
+    + page tables honouring the engine invariant (pages covering pos+1
+    tokens allocated, rest trash). Every layer holds different rows.
     Physical ids are a permutation — pages are deliberately NOT laid out
     in logical order — and the trash page holds violent garbage so any
     unmasked read is loud."""
     key = jax.random.PRNGKey(seed)
     p1 = slots * np_ + 1
     ks = jax.random.split(key, 5)
-    k_pages = jax.random.normal(ks[0], (p1, ps, kvh, d), jnp.float32)
-    v_pages = jax.random.normal(ks[1], (p1, ps, kvh, d), jnp.float32)
-    k_pages = k_pages.at[-1].set(37.0)
-    v_pages = v_pages.at[-1].set(-53.0)
+    pool = (LAYERS, p1, kvh, ps, d)
+    k_pages = jax.random.normal(ks[0], pool, jnp.float32)
+    v_pages = jax.random.normal(ks[1], pool, jnp.float32)
+    k_pages = k_pages.at[:, -1].set(37.0)
+    v_pages = v_pages.at[:, -1].set(-53.0)
     perm = np.random.RandomState(seed).permutation(p1 - 1)
     phys = np.full((slots, np_), p1 - 1, np.int64)
     for b, pos in enumerate(positions):
@@ -162,13 +170,17 @@ def _mk_case(seed, slots, np_, ps, kvh, g, d, positions):
 
 
 def _gather_out(q, k_pages, v_pages, k_new, v_new, phys, pos, window,
-                kv_start):
+                kv_start, layer):
+    """The legacy path: layer ``layer``'s gathered (B, T, KVH, D) view
+    through ``layers._sdpa_decode_combine``."""
     slots, np_ = phys.shape
-    ps, kvh, d = k_pages.shape[1], k_pages.shape[2], k_pages.shape[3]
-    view_k = k_pages[phys].reshape(slots, np_ * ps, kvh, d)
-    view_v = v_pages[phys].reshape(slots, np_ * ps, kvh, d)
-    return _sdpa_decode_combine(q, view_k, view_v, k_new, v_new, pos,
-                                window, kv_start=kv_start)
+    kvh, ps, d = k_pages.shape[2:]
+
+    def view(pool):
+        return jnp.swapaxes(pool[layer][phys], 2, 3).reshape(
+            slots, np_ * ps, kvh, d)
+    return _sdpa_decode_combine(q, view(k_pages), view(v_pages), k_new,
+                                v_new, pos, window, kv_start=kv_start)
 
 
 # positions: exactly on a page boundary (16), one past it (17), mid-page
@@ -176,21 +188,25 @@ def _gather_out(q, k_pages, v_pages, k_new, v_new, phys, pos, window,
 _POSITIONS = [16, 17, 9, -1]
 
 
+@pytest.mark.parametrize("layer", [0, LAYERS - 1])
 @pytest.mark.parametrize("impl", ["ref", "pallas"])
 @pytest.mark.parametrize("kvh,g", [(2, 1), (2, 3)])         # MHA and GQA
 @pytest.mark.parametrize("window,kv_start", [(0, 0), (11, 0), (0, 5),
                                              (11, 5)])
-def test_flash_matches_oracle_and_gather(impl, kvh, g, window, kv_start):
+def test_flash_matches_oracle_and_gather(impl, kvh, g, window, kv_start,
+                                         layer):
     q, kp, vp, kn, vn, phys, pos = _mk_case(
         seed=3, slots=4, np_=4, ps=8, kvh=kvh, g=g, d=16,
         positions=_POSITIONS)
-    out = flash_decode_paged(q, kp, vp, kn, vn, phys, pos, window=window,
-                             kv_start=kv_start, impl=impl, interpret=True)
+    out = flash_decode_paged(q, kp, vp, kn, vn, phys, pos, layer=layer,
+                             window=window, kv_start=kv_start, impl=impl,
+                             interpret=True)
     oracle = flash_decode_ref(q, kp, vp, kn, vn, phys, pos, window,
-                              kv_start)
+                              kv_start, layer=layer)
     np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
                                rtol=2e-4, atol=2e-4)
-    gather = _gather_out(q, kp, vp, kn, vn, phys, pos, window, kv_start)
+    gather = _gather_out(q, kp, vp, kn, vn, phys, pos, window, kv_start,
+                         layer)
     np.testing.assert_allclose(np.asarray(out), np.asarray(gather),
                                rtol=2e-4, atol=2e-4)
 
@@ -200,9 +216,9 @@ def test_flash_single_slot_batch(impl):
     """B=1 decode (the other batch-shape extreme of the grid)."""
     q, kp, vp, kn, vn, phys, pos = _mk_case(
         seed=11, slots=1, np_=4, ps=8, kvh=2, g=2, d=16, positions=[24])
-    out = flash_decode_paged(q, kp, vp, kn, vn, phys, pos, impl=impl,
-                             interpret=True)
-    oracle = flash_decode_ref(q, kp, vp, kn, vn, phys, pos)
+    out = flash_decode_paged(q, kp, vp, kn, vn, phys, pos, layer=1,
+                             impl=impl, interpret=True)
+    oracle = flash_decode_ref(q, kp, vp, kn, vn, phys, pos, layer=1)
     np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
                                rtol=2e-4, atol=2e-4)
 
@@ -220,7 +236,8 @@ def test_paged_split_count_invariance(sp):
     ks = jnp.zeros((b,), jnp.int32)
     pad = (-phys.shape[1]) % sp
     phys_p = jnp.pad(phys, ((0, 0), (0, pad)),
-                     constant_values=kp.shape[0] - 1)
+                     constant_values=kp.shape[1] - 1)
+    kp, vp = kp[0], vp[0]
     got = reduce_splits(*flash_decode_splits(qg, kp, vp, phys_p, pos, win,
                                              ks, sp))
     want = reduce_splits(*flash_decode_splits(qg, kp, vp, phys, pos, win,
@@ -239,8 +256,9 @@ def test_trash_page_contents_never_attended():
     for impl in ("ref", "pallas"):
         a = flash_decode_paged(q, kp, vp, kn, vn, phys, pos, impl=impl,
                                interpret=True)
-        b = flash_decode_paged(q, kp.at[-1].set(-1e4), vp.at[-1].set(1e4),
-                               kn, vn, phys, pos, impl=impl, interpret=True)
+        b = flash_decode_paged(q, kp.at[:, -1].set(-1e4),
+                               vp.at[:, -1].set(1e4), kn, vn, phys, pos,
+                               impl=impl, interpret=True)
         live = np.asarray(pos) >= 0
         np.testing.assert_array_equal(np.asarray(a)[live],
                                       np.asarray(b)[live])

@@ -9,7 +9,8 @@ Layers of evidence, innermost out:
   2. the quantized kernels — the LUT-accumulate "ref" impl and the
      dequant-in-VMEM Pallas grid — match the dequantize-then-reference
      oracle ``kernels.ref.flash_decode_kvq_ref`` across GQA / window /
-     kv_start / page-boundary / inactive-lane grids;
+     kv_start / page-boundary / inactive-lane / layer grids, each
+     reading one layer of a stacked head-major code pool at its index;
   3. model-level decode chains over a quantized pool are token-identical
      across the gather / ref / pallas read paths, for dense and
      ``lut_infer`` weights (both lossy paths stacked) and for gemma-style
@@ -141,21 +142,28 @@ def test_codebook_validation_and_fingerprint():
 # kernel-level parity vs the dequantize-then-reference oracle
 # ---------------------------------------------------------------------------
 
+# layers of the synthetic stacked code pool; tests read its first and last
+LAYERS = 3
+
+
 def _mk_kvq_case(seed, slots, np_, ps, kvh, g, d, positions, nc=4, c=16):
-    """Synthetic quantized pool mirroring test_flash_decode._mk_case:
-    permuted physical pages, in-range random codes, a random codebook
-    with non-trivial per-head scales, fp q/k_new/v_new."""
+    """Synthetic stacked head-major quantized pool ``(LAYERS, P+1, KVH,
+    page, nc)`` mirroring test_flash_decode._mk_case: permuted physical
+    pages, in-range random codes, a random stacked codebook (one per
+    layer) with non-trivial per-head scales, fp q/k_new/v_new."""
     key = jax.random.PRNGKey(seed)
     rng = np.random.RandomState(seed)
     p1 = slots * np_ + 1
     v = d // nc
     ks = jax.random.split(key, 5)
-    kc = jnp.asarray(rng.randint(0, c, (p1, ps, kvh, nc)), jnp.uint8)
-    vc = jnp.asarray(rng.randint(0, c, (p1, ps, kvh, nc)), jnp.uint8)
-    cb = {"zk": jax.random.normal(ks[0], (nc, c, v), jnp.float32),
-          "zv": jax.random.normal(ks[1], (nc, c, v), jnp.float32),
-          "sk": jnp.asarray(np.abs(rng.randn(kvh)) + 0.5, jnp.float32),
-          "sv": jnp.asarray(np.abs(rng.randn(kvh)) + 0.5, jnp.float32)}
+    pool = (LAYERS, p1, kvh, ps, nc)
+    kc = jnp.asarray(rng.randint(0, c, pool), jnp.uint8)
+    vc = jnp.asarray(rng.randint(0, c, pool), jnp.uint8)
+    scale = lambda: jnp.asarray(np.abs(rng.randn(LAYERS, kvh)) + 0.5,
+                                jnp.float32)
+    cb = {"zk": jax.random.normal(ks[0], (LAYERS, nc, c, v), jnp.float32),
+          "zv": jax.random.normal(ks[1], (LAYERS, nc, c, v), jnp.float32),
+          "sk": scale(), "sv": scale()}
     perm = rng.permutation(p1 - 1)
     phys = np.full((slots, np_), p1 - 1, np.int64)
     for b, pos in enumerate(positions):
@@ -172,19 +180,22 @@ def _mk_kvq_case(seed, slots, np_, ps, kvh, g, d, positions, nc=4, c=16):
 _POSITIONS = [16, 17, 9, -1]
 
 
+@pytest.mark.parametrize("layer", [0, LAYERS - 1])
 @pytest.mark.parametrize("impl", ["ref", "pallas"])
 @pytest.mark.parametrize("kvh,g", [(2, 1), (2, 3)])          # MHA and GQA
 @pytest.mark.parametrize("window,kv_start", [(0, 0), (11, 0), (0, 5),
                                              (11, 5)])
-def test_kvq_flash_matches_dequant_oracle(impl, kvh, g, window, kv_start):
+def test_kvq_flash_matches_dequant_oracle(impl, kvh, g, window, kv_start,
+                                          layer):
     q, kc, vc, cb, kn, vn, phys, pos = _mk_kvq_case(
         seed=3, slots=4, np_=4, ps=8, kvh=kvh, g=g, d=16,
         positions=_POSITIONS)
-    out = flash_decode_paged(q, kc, vc, kn, vn, phys, pos, window=window,
-                             kv_start=kv_start, impl=impl, codebook=cb,
-                             interpret=True)
+    out = flash_decode_paged(q, kc, vc, kn, vn, phys, pos, layer=layer,
+                             window=window, kv_start=kv_start, impl=impl,
+                             codebook=cb, interpret=True)
     oracle = flash_decode_kvq_ref(q, kc, vc, cb, kn, vn, phys, pos,
-                                  window=window, kv_start=kv_start)
+                                  window=window, kv_start=kv_start,
+                                  layer=layer)
     np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
                                rtol=2e-4, atol=2e-4)
 
@@ -193,9 +204,10 @@ def test_kvq_flash_matches_dequant_oracle(impl, kvh, g, window, kv_start):
 def test_kvq_flash_single_slot_batch(impl):
     q, kc, vc, cb, kn, vn, phys, pos = _mk_kvq_case(
         seed=11, slots=1, np_=4, ps=8, kvh=2, g=2, d=16, positions=[24])
-    out = flash_decode_paged(q, kc, vc, kn, vn, phys, pos, impl=impl,
-                             codebook=cb, interpret=True)
-    oracle = flash_decode_kvq_ref(q, kc, vc, cb, kn, vn, phys, pos)
+    out = flash_decode_paged(q, kc, vc, kn, vn, phys, pos, layer=1,
+                             impl=impl, codebook=cb, interpret=True)
+    oracle = flash_decode_kvq_ref(q, kc, vc, cb, kn, vn, phys, pos,
+                                  layer=1)
     np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
                                rtol=2e-4, atol=2e-4)
 
@@ -208,9 +220,9 @@ def test_kvq_trash_page_codes_never_attended():
     for impl in ("ref", "pallas"):
         a = flash_decode_paged(q, kc, vc, kn, vn, phys, pos, impl=impl,
                                codebook=cb, interpret=True)
-        b = flash_decode_paged(q, kc.at[-1].set(15), vc.at[-1].set(0),
-                               kn, vn, phys, pos, impl=impl, codebook=cb,
-                               interpret=True)
+        b = flash_decode_paged(q, kc.at[:, -1].set(15),
+                               vc.at[:, -1].set(0), kn, vn, phys, pos,
+                               impl=impl, codebook=cb, interpret=True)
         live = np.asarray(pos) >= 0
         np.testing.assert_array_equal(np.asarray(a)[live],
                                       np.asarray(b)[live])
@@ -397,7 +409,7 @@ def test_kvq_exact_cover_engine_token_identity(qwen):
     assert toks == fp_out, "manual chain diverged from the engine"
     t_rows = p + n_new - 1                 # every row the run READS
     cfg = m.cfg
-    rows = {key: kv[key][:, np.arange(4)].reshape(
+    rows = {key: jnp.swapaxes(kv[key][:, np.arange(4)], 2, 3).reshape(
         cfg.num_layers, 32, cfg.num_kv_heads, cfg.head_dim)[:, :t_rows]
         for key in ("k", "v")}
     cb = KVCodebook.from_rows(rows["k"], rows["v"])

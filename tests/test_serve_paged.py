@@ -386,7 +386,7 @@ def test_write_rows_matches_scatter_off_the_trash_page(pool, idx):
     l, p, ps, kvh, w = 3, 6, 4, 2, 8          # trash page id p
     dtype, lo, hi = ((jnp.bfloat16, -4, 4) if pool == "fp"
                      else (jnp.uint8, 0, 256))
-    base = jnp.asarray(rng.uniform(lo, hi, (l, p + 1, ps, kvh, w)), dtype)
+    base = jnp.asarray(rng.uniform(lo, hi, (l, p + 1, kvh, ps, w)), dtype)
     rows = jnp.asarray(rng.uniform(lo, hi, (l, *idx, kvh, w)), dtype)
     n = int(np.prod(idx))
     cells = rng.choice(p * ps, n, replace=False)
@@ -396,7 +396,8 @@ def test_write_rows_matches_scatter_off_the_trash_page(pool, idx):
     off = np.where(live, cells % ps, rng.integers(0, 2, n))
     tgt, off = jnp.asarray(tgt.reshape(idx)), jnp.asarray(off.reshape(idx))
     got = jax.jit(_write_rows)(base, rows, tgt, off)
-    want = base.at[:, tgt, off].set(rows)
+    want = jnp.swapaxes(jnp.swapaxes(base, 2, 3).at[:, tgt, off].set(rows),
+                        2, 3)
     assert got.dtype == base.dtype and got.shape == base.shape
     np.testing.assert_array_equal(np.asarray(got[:, :p], np.float32),
                                   np.asarray(want[:, :p], np.float32))

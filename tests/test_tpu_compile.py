@@ -91,39 +91,48 @@ def test_vq_amm_compiles(one_chip, m, k, n):
 
 @pytest.mark.parametrize("pool", ["fp", "vq"])
 def test_flash_decode_compiles(one_chip, pool):
+    """The split pass over a stacked two-layer head-major pool, read at a
+    traced layer index."""
     s = _spec(one_chip)
     p1 = SLOTS * PAGES_PER_SLOT + 1
     tok = s((SLOTS, 1, KVH, HD), jnp.bfloat16)
     if pool == "vq":                   # kv_v=4, kv_c=16 codes over head_dim
         nc, kc, kv = HD // 4, 16, 4
-        pages = s((p1, PAGE, KVH, nc), jnp.uint8)
-        cb = {"zk": s((nc, kc, kv), jnp.float32),
-              "zv": s((nc, kc, kv), jnp.float32),
-              "sk": s((KVH,), jnp.float32), "sv": s((KVH,), jnp.float32)}
+        pages = s((2, p1, KVH, PAGE, nc), jnp.uint8)
+        cb = {"zk": s((2, nc, kc, kv), jnp.float32),
+              "zv": s((2, nc, kc, kv), jnp.float32),
+              "sk": s((2, KVH), jnp.float32), "sv": s((2, KVH), jnp.float32)}
     else:
-        pages = s((p1, PAGE, KVH, HD), jnp.bfloat16)
+        pages = s((2, p1, KVH, PAGE, HD), jnp.bfloat16)
         cb = None
 
-    def step(q, kp, vp, kn, vn, phys, pos, cb):
-        return flash_decode_paged(q, kp, vp, kn, vn, phys, pos,
+    def step(q, kp, vp, kn, vn, phys, pos, layer, cb):
+        return flash_decode_paged(q, kp, vp, kn, vn, phys, pos, layer=layer,
                                   impl="pallas", codebook=cb)
 
     hlo = _compile(step, tok, pages, pages, tok, tok,
                    s((SLOTS, PAGES_PER_SLOT), jnp.int32),
-                   s((SLOTS,), jnp.int32), cb)
+                   s((SLOTS,), jnp.int32), s((), jnp.int32), cb)
     assert "tpu_custom_call" in hlo
 
 
-def _step_hlo(one_chip, program):
-    """``program`` ("decode_step" or "prefill_chunk") of the engine at
-    Qwen1.5-4B's widths (8 slots of 1024, pages of 16, a pool of 512
-    pages, chunks of 32), compiled with the pool donated as the engine
-    does. Returns (HLO text, the pool's HLO shape)."""
+# configurations of the step compiles: Qwen1.5-4B (20 kv heads) and
+# Yi-9B (4 kv heads), Yi cut to Qwen's 40 layers so that its bf16 weights
+# fit one v5e's 16 GB
+STEP_MODELS = {"qwen1.5-4b": {}, "yi-9b": {"num_layers": 40}}
+
+
+def _step_hlo(one_chip, program, name="qwen1.5-4b"):
+    """``program`` ("decode_step" or "prefill_chunk") of the engine at the
+    widths of ``name`` (a key of ``STEP_MODELS``; 8 slots of 1024, pages
+    of 16, a pool of 512 pages, chunks of 32), compiled with the pool
+    donated as the engine does. Returns (HLO text, the pool's HLO
+    shape)."""
     from repro.configs import get_config
     from repro.core.lut import DENSE
     from repro.models.model import Model
     from repro.serve.engine import step_programs
-    cfg = get_config("qwen1.5-4b")
+    cfg = get_config(name).replace(**STEP_MODELS[name])
     model = Model(cfg)
     qc = DENSE.replace(flash="pallas")
     s = _spec(one_chip)
@@ -157,6 +166,59 @@ def _computations(hlo):
         elif comp is not None:
             comps[comp].append(line.strip())
     return comps
+
+
+def _instructions(comps):
+    """{computation: {instruction name: (result dims, opcode, operand
+    names, the line)}}: dims is None for a tuple result."""
+    import re
+    head = re.compile(r"(?:ROOT )?%([\w.-]+) = ")
+    dims = re.compile(r"\w+\[([\d,]*)\]")
+    out = {}
+    for comp, body in comps.items():
+        table = out[comp] = {}
+        for ins in body:
+            m = head.match(ins)
+            if not m:
+                continue
+            rest = ins[m.end():]
+            op = re.search(r" ([a-z][\w-]*)\(", rest)
+            args, depth = op.end(), 1
+            for i in range(op.end(), len(rest)):
+                depth += {"(": 1, ")": -1}.get(rest[i], 0)
+                if not depth:
+                    args = rest[op.end():i]
+                    break
+            d = None if rest.startswith("(") else dims.match(rest)
+            table[m.group(1)] = (
+                None if d is None else tuple(map(int, filter(None,
+                                                 d.group(1).split(",")))),
+                op.group(1), re.findall(r"%([\w.-]+)", args), ins)
+    return out
+
+
+def _source(table, comp, name):
+    """Follow ``name`` in ``comp`` back through bitcasts and loop-carried
+    tuple elements to the instruction that makes it: (computation, its
+    entry in ``table``)."""
+    import re
+    while True:
+        dims, op, args, ins = table[comp][name]
+        if op == "bitcast":
+            name = args[0]
+        elif (op == "get-tuple-element"
+              and table[comp][args[0]][1] == "parameter"
+              and not comp.startswith("ENTRY")):
+            index = int(re.search(r"index=(\d+)", ins).group(1))
+            loops = [(c, t) for c, ts in table.items() for t in ts.values()
+                     if t[1] == "while" and f"body={comp}" in t[3]]
+            assert len(loops) == 1, (comp, len(loops))
+            comp, (_, _, (init,), _) = loops[0]
+            dims, op, args, ins = table[comp][init]
+            assert op == "tuple", ins[:120]
+            name = args[index]
+        else:
+            return comp, table[comp][name]
 
 
 def test_decode_step_names_its_program_and_flash_kernel(one_chip,
@@ -219,6 +281,59 @@ def test_step_writes_kv_rows_in_place(one_chip, monkeypatch, program):
     header = hlo.split("entry_computation_layout=", 1)[0]
     aliased = {int(n) for n in re.findall(r"\{\d+\}: \((\d+), ", header)}
     assert pool_params <= aliased, (pool_params, header)
+
+
+@pytest.mark.parametrize("name", sorted(STEP_MODELS))
+def test_decode_step_reads_pool_layers_in_place(one_chip, monkeypatch,
+                                                name):
+    """The decode step reads each layer of the stacked head-major K/V pool
+    where it lies, for 20 kv heads and for 4: no instruction's result is
+    one layer of the pool in any axis order (a per-layer dynamic-slice,
+    or its relayout copy), none relays out the whole pool, and the flash
+    split call takes the entry's own pool parameters, passed through the
+    layer loop and bitcasts only."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo, pool = _step_hlo(one_chip, "decode_step", name)
+    whole = tuple(map(int, pool[5:-1].split(",")))
+    layer = sorted(whole[1:])
+    table = _instructions(_computations(hlo))
+    for comp, ts in table.items():
+        for dims, op, _, ins in ts.values():
+            if dims is None:
+                continue
+            assert sorted(d for d in dims if d != 1) != layer, ins[:120]
+            if op in ("copy", "transpose", "scatter"):
+                assert sorted(dims) != sorted(whole), ins[:120]
+    calls = [(c, t) for c, ts in table.items() for t in ts.values()
+             if t[1] == "custom-call" and t[3].startswith("%flash_splits.")]
+    assert len(calls) == 1
+    comp, (_, _, args, _) = calls[0]
+    params = set()
+    for arg in args[-2:]:                       # the K and V pools
+        where, (dims, op, _, ins) = _source(table, comp, arg)
+        assert where.startswith("ENTRY") and op == "parameter", ins[:120]
+        assert dims == whole
+        params.add(ins)
+    assert len(params) == 2
+
+
+@pytest.mark.parametrize("name", sorted(STEP_MODELS))
+def test_prefill_chunk_gathers_view_without_copy(one_chip, monkeypatch,
+                                                 name):
+    """The prefill chunk gathers the slot's pages of the head-major pool
+    into its dense (L, 1, 1024, KVH, HD) view without a second pass over
+    it: no copy or transpose has the view's size."""
+    import math
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo, pool = _step_hlo(one_chip, "prefill_chunk", name)
+    l, _, kvh, page, hd = map(int, pool[5:-1].split(","))
+    view = l * PAGES_PER_SLOT * page * kvh * hd
+    table = _instructions(_computations(hlo))
+    relaid = [ins[:120] for ts in table.values()
+              for dims, op, _, ins in ts.values()
+              if dims is not None and op in ("copy", "transpose")
+              and math.prod(dims) == view]
+    assert not relaid, relaid
 
 
 # Moonlight-16B-A3B's widths: 16 heads of latent attention over a latent

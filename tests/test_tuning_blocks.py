@@ -77,10 +77,11 @@ def _case(seed, slots, np_, ps, kvh, g, d, positions):
     key = jax.random.PRNGKey(seed)
     p1 = slots * np_ + 1
     ks = jax.random.split(key, 5)
-    k_pages = jax.random.normal(ks[0], (p1, ps, kvh, d), jnp.float32)
-    v_pages = jax.random.normal(ks[1], (p1, ps, kvh, d), jnp.float32)
-    k_pages = k_pages.at[-1].set(41.0)     # loud trash page
-    v_pages = v_pages.at[-1].set(-59.0)
+    pool = (2, p1, kvh, ps, d)             # stacked head-major, 2 layers
+    k_pages = jax.random.normal(ks[0], pool, jnp.float32)
+    v_pages = jax.random.normal(ks[1], pool, jnp.float32)
+    k_pages = k_pages.at[:, -1].set(41.0)  # loud trash page
+    v_pages = v_pages.at[:, -1].set(-59.0)
     phys = np.full((slots, np_), p1 - 1, np.int64)
     nxt = 0
     for b, pos in enumerate(positions):
@@ -101,8 +102,8 @@ def _case(seed, slots, np_, ps, kvh, g, d, positions):
 ])
 def test_non_pow2_head_count_parity(kvh, g, block_heads):
     args = _case(3, 2, 4, 8, kvh, g, 16, [5, 13])
-    out = flash_decode_paged(*args, impl="pallas", interpret=True,
+    out = flash_decode_paged(*args, layer=1, impl="pallas", interpret=True,
                              block_heads=block_heads)
-    oracle = flash_decode_ref(*args)
+    oracle = flash_decode_ref(*args, layer=1)
     np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
                                rtol=1e-5, atol=1e-5)
